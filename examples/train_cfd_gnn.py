@@ -3,15 +3,12 @@
 Trains the paper's 'small' GNN on Taylor-Green-vortex snapshots over a
 partitioned SEM mesh with REAL collectives (shard_map over a (data, graph)
 device mesh), AdamW, async checkpointing + restart, and straggler monitoring.
-Uses 8 host devices (set before jax import).
+Needs 8 devices; on a CPU host, give it fake host devices:
 
-    PYTHONPATH=src python examples/train_cfd_gnn.py [--steps 300]
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/train_cfd_gnn.py [--steps 300]
 """
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
-
 
 from repro.core import GNNConfig, box_mesh, partition_mesh
 from repro.launch.mesh import make_mesh

@@ -1,9 +1,4 @@
 """repro: consistent distributed mesh-based GNNs in JAX (SC24-W reproduction
-+ TPU-pod framework). See README.md / DESIGN.md / EXPERIMENTS.md."""
++ TPU-pod framework). See ROADMAP.md / CONTRIBUTING.md."""
 
 __version__ = "1.0.0"
-
-from repro import compat as _compat
-
-_compat.install()
-del _compat

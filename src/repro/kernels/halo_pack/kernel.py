@@ -31,14 +31,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.segment_agg.kernel import _gather_rows, _scatter_add_rows
+from repro.kernels.segment_agg.kernel import (
+    COMPILER_PARAMS, _gather_rows, _row_bytes, _scatter_add_rows, check_fits,
+    index_list_bytes, lane_pad)
 
 
-def _pack_kernel(idx_ref, x_any, mask_ref, buf_ref, gat, sem, *, block_b):
+def _pack_kernel(idx_ref, x_any, mask_ref, buf_ref, gat, sem, *, block_b,
+                 feat):
     t = pl.program_id(0)
     nt = pl.num_programs(0)
-    rows = _gather_rows(idx_ref, t, nt, x_any, gat, sem, block_b)
-    buf_ref[0] = (rows * mask_ref[0][:, None]).astype(buf_ref.dtype)
+    rows = _gather_rows(idx_ref, t, nt, x_any, gat, sem, block_b)[:, :feat]
+    buf_ref[0] = (rows * mask_ref[0].T).astype(buf_ref.dtype)
 
 
 def pack_pallas(x: jnp.ndarray, idx_t: jnp.ndarray, mask_t: jnp.ndarray,
@@ -50,29 +53,34 @@ def pack_pallas(x: jnp.ndarray, idx_t: jnp.ndarray, mask_t: jnp.ndarray,
     """
     n_tiles, block_b = idx_t.shape
     feat = x.shape[1]
+    if not interpret:
+        check_fits("pack_pallas", index_list_bytes(n_tiles, block_b),
+                   6 * block_b * _row_bytes(feat))
+    x = lane_pad(x)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),          # x: manual DMA
-            pl.BlockSpec((1, block_b), lambda t, *_: (t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),          # x: manual DMA
+            pl.BlockSpec((1, 1, block_b), lambda t, *_: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_b, feat), lambda t, *_: (t, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, block_b, feat), x.dtype),
+            pltpu.VMEM((2, block_b, x.shape[1]), x.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_pack_kernel, block_b=block_b),
+        functools.partial(_pack_kernel, block_b=block_b, feat=feat),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, block_b, feat), x.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(idx_t, x, mask_t)
+    )(idx_t, x, mask_t[:, None])
 
 
-def _unpack_kernel(idx_ref, a_ref, buf_ref, mask_ref, out_ref, acc, *,
-                   block_b):
+def _unpack_kernel(idx_ref, a_ref, buf_ref, mask_ref, out_ref, rows_scr, acc,
+                   *, block_b):
     t = pl.program_id(0)
     nt = pl.num_programs(0)
 
@@ -80,8 +88,8 @@ def _unpack_kernel(idx_ref, a_ref, buf_ref, mask_ref, out_ref, acc, *,
     def _init():
         acc[...] = a_ref[...]
 
-    rows = buf_ref[0] * mask_ref[0][:, None]
-    _scatter_add_rows(idx_ref, t, rows, acc, block_b)
+    rows = buf_ref[0] * mask_ref[0].T
+    _scatter_add_rows(idx_ref, t, rows, rows_scr, acc, block_b)
 
     @pl.when(t == nt - 1)
     def _flush():
@@ -99,20 +107,26 @@ def unpack_add_pallas(a: jnp.ndarray, buf_t: jnp.ndarray, idx_t: jnp.ndarray,
     """
     n_tiles, block_b = idx_t.shape
     n_rows, feat = a.shape
+    if not interpret:
+        # the accumulator stays resident twice (scratch and output block)
+        check_fits("unpack_add_pallas", index_list_bytes(n_tiles, block_b),
+                   (2 * n_rows + 8 * block_b) * _row_bytes(feat))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((n_rows, feat), lambda t, *_: (0, 0)),
             pl.BlockSpec((1, block_b, feat), lambda t, *_: (t, 0, 0)),
-            pl.BlockSpec((1, block_b), lambda t, *_: (t, 0)),
+            pl.BlockSpec((1, 1, block_b), lambda t, *_: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((n_rows, feat), lambda t, *_: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((n_rows, feat), a.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_b, feat), a.dtype),
+                        pltpu.VMEM((n_rows, feat), a.dtype)],
     )
     return pl.pallas_call(
         functools.partial(_unpack_kernel, block_b=block_b),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows, feat), a.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(idx_t, a, buf_t, mask_t)
+    )(idx_t, a, buf_t, mask_t[:, None])

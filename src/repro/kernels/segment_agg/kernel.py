@@ -36,11 +36,12 @@ aggregation accumulator and all gradient accumulators stay fp32 either way.
 summation order and is what the consistency tests pin.
 
 VMEM note: the fused forward holds the ``[N_round, H]`` *aggregate* (and
-the backward additionally the node-gradient accumulator) in VMEM scratch;
-the node features themselves stay in HBM/ANY and are streamed by rows.
-SMEM note: the prefetched index lists are ``[n_tiles, BE]`` int32 — 4·E
-bytes per operand; shard the graph harder (or raise ``block_e``) before
-per-rank E makes that exceed SMEM.
+the backward the node-gradient accumulator) in VMEM; the node features
+stay in HBM/ANY and are streamed by lane-padded rows (:func:`lane_pad`).
+SMEM note: the prefetched index lists are ``[n_tiles, BE]`` int32, 4 bytes
+per edge slot each. :func:`check_fits` refuses a graph past either limit
+before tracing (on a v5e, about 21k nodes per rank at H=32); shard the
+graph over more ranks.
 """
 from __future__ import annotations
 
@@ -58,11 +59,12 @@ PRECISIONS = (FP32, BF16)
 
 def _dot(a, b, precision: str):
     """Matmul with the kernel's precision policy: bf16 operands / fp32
-    accumulation when ``precision == "bf16"``, plain fp32 otherwise."""
+    accumulation when ``precision == "bf16"``, true fp32 otherwise (as in
+    ``repro.nn.dense``)."""
     if precision == BF16:
         return jax.lax.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
                            preferred_element_type=jnp.float32)
-    return jax.lax.dot(a, b)
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _kernel(feats_ref, dstl_ref, wgt_ref, w1_ref, b1_ref, w2_ref, b2_ref,
@@ -98,9 +100,74 @@ def _kernel(feats_ref, dstl_ref, wgt_ref, w1_ref, b1_ref, w2_ref, b2_ref,
 # scalar-prefetch DMA gather / scatter helpers (shared by the fused pair)
 # ---------------------------------------------------------------------------
 
+#: lanes of one TPU vector tile: a row DMA moves whole lane tiles
+LANES = 128
+#: scoped VMEM the fused kernels may use (XLA's default is 16 MiB; a v5e
+#: core has 128 MiB)
+VMEM_LIMIT_BYTES = 64 << 20
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+#: SMEM for scalar-prefetched index lists: a v5e core has 1 MiB, of which
+#: Mosaic keeps about 1 KiB for itself (4 KiB are held back)
+SMEM_BUDGET_BYTES = (1 << 20) - (4 << 10)
+
+
+def _row_bytes(width: int) -> int:
+    """VMEM bytes of one fp32 row: the minor dim is padded to whole lanes."""
+    return -(-width // LANES) * LANES * 4
+
+
+def check_fits(kernel: str, smem_bytes: int, vmem_bytes: int) -> None:
+    """Refuse, before tracing, a call whose index lists exceed SMEM or whose
+    resident arrays exceed the scoped VMEM limit: the design keeps every
+    index list in SMEM and each full-size accumulator in VMEM (ROADMAP R1
+    lifts both). Only compiled (``interpret=False``) calls are checked."""
+    if smem_bytes > SMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"{kernel}: the scalar-prefetched index lists need {smem_bytes} B "
+            f"of SMEM, over the {SMEM_BUDGET_BYTES} B budget of a TPU core's "
+            "1 MiB SMEM; partition the graph over more ranks")
+    if vmem_bytes > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"{kernel}: the resident accumulators need about {vmem_bytes} B "
+            f"of VMEM, over the kernel's {VMEM_LIMIT_BYTES} B scoped VMEM "
+            "limit; partition the graph over more ranks")
+
+
+def index_list_bytes(n_tiles: int, block: int) -> int:
+    """SMEM bytes of one ``[T, BLOCK]`` int32 index list (SMEM pads the
+    rows to a multiple of 8 and the columns to whole lanes)."""
+    return -(-n_tiles // 8) * 8 * _row_bytes(block)
+
+
+def check_fused_nmp_fits(n_round: int, hidden: int, n_tiles: int,
+                         block_e: int, *, backward: bool) -> None:
+    """Size limit of the fused NMP pair: two ``[T, BE]`` int32 index lists in
+    SMEM; the ``[N_round, H]`` accumulator twice in VMEM (scratch and output
+    block) beside the edge tiles (about 16 ``[BE, 128]`` fp32 buffers in the
+    forward, 24 in the backward)."""
+    tiles = (24 if backward else 16) * block_e * _row_bytes(LANES)
+    check_fits("nmp_edge_mlp_agg_bwd" if backward else "nmp_edge_mlp_agg_fwd",
+               2 * index_list_bytes(n_tiles, block_e),
+               2 * n_round * _row_bytes(hidden) + tiles)
+
+
+def lane_pad(x):
+    """Zero-pad the minor dim of ``x`` [N, F] up to a multiple of 128 lanes.
+
+    Mosaic refuses a row DMA narrower than one lane tile ("Slice shape along
+    dimension 1 must be aligned to tiling (128), but is 32"), so the HBM
+    operands of the row gathers carry lane-padded rows and the kernels slice
+    the real width back out of the gathered tile.
+    """
+    f = x.shape[-1]
+    f_pad = -(-f // LANES) * LANES
+    return x if f_pad == f else jnp.pad(x, ((0, 0), (0, f_pad - f)))
+
+
 def _gather_rows(idx_ref, t, nt, src_ref, buf, sem, block_e: int):
     """Double-buffered row gather: rows ``idx_ref[t, :]`` of ``src_ref``
-    (HBM/ANY) land in ``buf[t % 2]`` (VMEM ``[2, BE, H]``).
+    (HBM/ANY, lane-padded by :func:`lane_pad`) land in ``buf[t % 2]`` (VMEM
+    ``[2, BE, F_pad]``).
 
     At tile t the copies for tile t+1 are issued into the other slot before
     waiting on tile t's — the next tile's rows stream in under this tile's
@@ -132,20 +199,28 @@ def _gather_rows(idx_ref, t, nt, src_ref, buf, sem, block_e: int):
     return buf[t % 2]
 
 
-def _scatter_add_rows(idx_ref, t, rows, acc, block_e: int):
+def _scatter_add_rows(idx_ref, t, rows, rows_scr, acc, block_e: int):
     """Sequential per-row read-modify-write: ``acc[idx_ref[t, k]] += rows[k]``.
 
+    ``rows`` is staged in the VMEM scratch ``rows_scr`` first: a dynamic row
+    slice of a ref lowers on the TPU, a dynamic slice of a value does not.
     Duplicate destinations within the tile are handled by the loop's
     sequential semantics; padding slots carry zero rows (weight-masked), so
     their writes to row 0 are no-ops.
     """
+    rows_scr[...] = rows.astype(rows_scr.dtype)
+
     def body(k, _):
         r = idx_ref[t, k]
-        cur = pl.load(acc, (pl.ds(r, 1), slice(None)))
-        pl.store(acc, (pl.ds(r, 1), slice(None)),
-                 cur + jax.lax.dynamic_slice_in_dim(rows, k, 1, axis=0))
+        acc[pl.ds(r, 1), :] = acc[pl.ds(r, 1), :] + rows_scr[pl.ds(k, 1), :]
         return 0
     jax.lax.fori_loop(0, block_e, body, 0)
+
+
+def _elu(h):
+    """ELU from ``exp``: Pallas has no TPU lowering for ``expm1``, which
+    ``jax.nn.elu`` uses (the two agree to fp32 rounding)."""
+    return jnp.where(h > 0, h, jnp.exp(jnp.minimum(h, 0.0)) - 1.0)
 
 
 def _edge_mlp_tile(xi, xj, et, mask, w0, b0, wrest, brest, lng, lnb, *,
@@ -160,14 +235,14 @@ def _edge_mlp_tile(xi, xj, et, mask, w0, b0, wrest, brest, lng, lnb, *,
          + _dot(xj, w0[hidden:2 * hidden], precision)
          + _dot(et, w0[2 * hidden:], precision) + b0[0])
     for l in range(n_hidden):
-        h = jax.nn.elu(h)
+        h = _elu(h)
         h = _dot(h, wrest[l], precision) + brest[l]
     if has_ln:
         mu = jnp.mean(h, axis=-1, keepdims=True)
         var = jnp.var(h, axis=-1, keepdims=True)
         h = (h - mu) * jax.lax.rsqrt(var + eps)
         h = h * lng[0] + lnb[0]
-    return (et + h) * mask[:, None]
+    return (et + h) * mask
 
 
 # ---------------------------------------------------------------------------
@@ -176,9 +251,9 @@ def _edge_mlp_tile(xi, xj, et, mask, w0, b0, wrest, brest, lng, lnb, *,
 
 def _nmp_fwd_kernel(srcg_ref, dstg_ref, x_any, e_ref, emask_ref, einv_ref,
                     w0_ref, b0_ref, wrest_ref, brest_ref, lng_ref, lnb_ref,
-                    enew_ref, agg_ref, xi_buf, xj_buf, agg_scr, sem_src,
-                    sem_dst, *, block_e: int, hidden: int, n_hidden: int,
-                    has_ln: bool, precision: str):
+                    enew_ref, agg_ref, xi_buf, xj_buf, rows_scr, agg_scr,
+                    sem_src, sem_dst, *, block_e: int, hidden: int,
+                    n_hidden: int, has_ln: bool, precision: str):
     """Fused Eq. 4a+4b tile: DMA-gather src/dst node rows, run the full
     residual edge MLP (incl. LayerNorm), mask, and scatter the 1/d_ij-
     weighted contribution into the fp32 VMEM aggregate."""
@@ -190,12 +265,12 @@ def _nmp_fwd_kernel(srcg_ref, dstg_ref, x_any, e_ref, emask_ref, einv_ref,
         agg_scr[...] = jnp.zeros_like(agg_scr)
 
     xi = _gather_rows(srcg_ref, t, nt, x_any, xi_buf, sem_src,
-                      block_e).astype(jnp.float32)        # [BE, H]
+                      block_e)[:, :hidden].astype(jnp.float32)   # [BE, H]
     xj = _gather_rows(dstg_ref, t, nt, x_any, xj_buf, sem_dst,
-                      block_e).astype(jnp.float32)        # [BE, H]
+                      block_e)[:, :hidden].astype(jnp.float32)   # [BE, H]
     et = e_ref[0].astype(jnp.float32)                     # [BE, H]
-    mask = emask_ref[0]                                   # [BE] 1/0
-    wgt = einv_ref[0]                                     # [BE] 1/d_ij (0 pad)
+    mask = emask_ref[0].T                                 # [BE, 1] 1/0
+    wgt = einv_ref[0].T                                   # [BE, 1] 1/d_ij (0 pad)
 
     e_new = _edge_mlp_tile(
         xi, xj, et, mask, w0_ref[...].astype(jnp.float32),
@@ -205,7 +280,7 @@ def _nmp_fwd_kernel(srcg_ref, dstg_ref, x_any, e_ref, emask_ref, einv_ref,
         has_ln=has_ln, precision=precision)
     enew_ref[0] = e_new.astype(enew_ref.dtype)
 
-    _scatter_add_rows(dstg_ref, t, e_new * wgt[:, None], agg_scr, block_e)
+    _scatter_add_rows(dstg_ref, t, e_new * wgt, rows_scr, agg_scr, block_e)
 
     @pl.when(t == nt - 1)
     def _flush():
@@ -227,6 +302,9 @@ def nmp_edge_mlp_agg_fwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
     T, BE, H = e_tiles.shape
     Lp = wrest.shape[0]
     n_round = x.shape[0]
+    x = lane_pad(x)
+    if not interpret:
+        check_fused_nmp_fits(n_round, H, T, BE, backward=False)
     kern = functools.partial(
         _nmp_fwd_kernel, block_e=BE, hidden=H, n_hidden=n_hidden,
         has_ln=has_ln, precision=precision)
@@ -234,10 +312,10 @@ def nmp_edge_mlp_agg_fwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),              # x (row DMA)
+            pl.BlockSpec(memory_space=pl.ANY),              # x (row DMA)
             pl.BlockSpec((1, BE, H), lambda t, *_: (t, 0, 0)),
-            pl.BlockSpec((1, BE), lambda t, *_: (t, 0)),
-            pl.BlockSpec((1, BE), lambda t, *_: (t, 0)),
+            pl.BlockSpec((1, 1, BE), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec((1, 1, BE), lambda t, *_: (t, 0, 0)),
             pl.BlockSpec((3 * H, H), lambda t, *_: (0, 0)),
             pl.BlockSpec((1, H), lambda t, *_: (0, 0)),
             pl.BlockSpec((Lp, H, H), lambda t, *_: (0, 0, 0)),
@@ -250,8 +328,9 @@ def nmp_edge_mlp_agg_fwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
             pl.BlockSpec((n_round, H), lambda t, *_: (0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, BE, H), x.dtype),                   # xi double-buf
-            pltpu.VMEM((2, BE, H), x.dtype),                   # xj double-buf
+            pltpu.VMEM((2, BE, x.shape[1]), x.dtype),          # xi double-buf
+            pltpu.VMEM((2, BE, x.shape[1]), x.dtype),          # xj double-buf
+            pltpu.VMEM((BE, H), jnp.float32),                  # scatter rows
             pltpu.VMEM((n_round, H), jnp.float32),             # aggregate
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -264,8 +343,10 @@ def nmp_edge_mlp_agg_fwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
             jax.ShapeDtypeStruct((T, BE, H), e_tiles.dtype),
             jax.ShapeDtypeStruct((n_round, H), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(srcg, dstg, x, e_tiles, emask, einv, w0, b0, wrest, brest, lng, lnb)
+    )(srcg, dstg, x, e_tiles, emask[:, None], einv[:, None], w0, b0, wrest,
+      brest, lng, lnb)
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +358,7 @@ def _nmp_bwd_kernel(srcg_ref, dstg_ref, x_any, gagg_any, e_ref, emask_ref,
                     lnb_ref, genew_ref,
                     gx_ref, ge_ref, gw0_ref, gb0_ref, gwrest_ref, gbrest_ref,
                     glng_ref, glnb_ref,
-                    xi_buf, xj_buf, gag_buf, gx_scr, gw0_scr, gb0_scr,
+                    xi_buf, xj_buf, gag_buf, rows_scr, gx_scr, gw0_scr, gb0_scr,
                     gwrest_scr, gbrest_scr, glng_scr, glnb_scr, sem_src,
                     sem_dst, sem_gag, *, block_e: int, hidden: int,
                     n_hidden: int, has_ln: bool, precision: str):
@@ -305,13 +386,13 @@ def _nmp_bwd_kernel(srcg_ref, dstg_ref, x_any, gagg_any, e_ref, emask_ref,
         glnb_scr[...] = jnp.zeros_like(glnb_scr)
 
     xi = _gather_rows(srcg_ref, t, nt, x_any, xi_buf, sem_src,
-                      block_e).astype(jnp.float32)
+                      block_e)[:, :hidden].astype(jnp.float32)
     xj = _gather_rows(dstg_ref, t, nt, x_any, xj_buf, sem_dst,
-                      block_e).astype(jnp.float32)
+                      block_e)[:, :hidden].astype(jnp.float32)
     gag = _gather_rows(dstg_ref, t, nt, gagg_any, gag_buf, sem_gag,
-                       block_e).astype(jnp.float32)
-    mask = emask_ref[0]
-    wgt = einv_ref[0]
+                       block_e)[:, :hidden].astype(jnp.float32)
+    mask = emask_ref[0].T
+    wgt = einv_ref[0].T
 
     def tile_fwd(xi, xj, et, w0, b0, wrest, brest, lng, lnb):
         # identical arithmetic to the forward tile (incl. the precision
@@ -330,12 +411,12 @@ def _nmp_bwd_kernel(srcg_ref, dstg_ref, x_any, gagg_any, e_ref, emask_ref,
     _, vjp = jax.vjp(tile_fwd, *args)
     # e_new feeds both outputs: its cotangent is g_enew plus the weighted
     # rows of g_agg its scatter-add contributed to
-    g_e_new = genew_ref[0].astype(jnp.float32) + gag * wgt[:, None]
+    g_e_new = genew_ref[0].astype(jnp.float32) + gag * wgt
     gxi, gxj, ge, gw0, gb0, gwrest, gbrest, glng, glnb = vjp(g_e_new)
 
     ge_ref[0] = ge.astype(ge_ref.dtype)
-    _scatter_add_rows(srcg_ref, t, gxi, gx_scr, block_e)
-    _scatter_add_rows(dstg_ref, t, gxj, gx_scr, block_e)
+    _scatter_add_rows(srcg_ref, t, gxi, rows_scr, gx_scr, block_e)
+    _scatter_add_rows(dstg_ref, t, gxj, rows_scr, gx_scr, block_e)
     gw0_scr[...] += gw0
     gb0_scr[...] += gb0
     gwrest_scr[...] += gwrest
@@ -367,6 +448,9 @@ def nmp_edge_mlp_agg_bwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
     T, BE, H = e_tiles.shape
     Lp = wrest.shape[0]
     n_round = x.shape[0]
+    x, g_agg = lane_pad(x), lane_pad(g_agg)
+    if not interpret:
+        check_fused_nmp_fits(n_round, H, T, BE, backward=True)
     kern = functools.partial(
         _nmp_bwd_kernel, block_e=BE, hidden=H, n_hidden=n_hidden,
         has_ln=has_ln, precision=precision)
@@ -375,11 +459,11 @@ def nmp_edge_mlp_agg_bwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),              # x
-            pl.BlockSpec(memory_space=pltpu.ANY),              # g_agg
+            pl.BlockSpec(memory_space=pl.ANY),              # x
+            pl.BlockSpec(memory_space=pl.ANY),              # g_agg
             pl.BlockSpec((1, BE, H), lambda t, *_: (t, 0, 0)),
-            pl.BlockSpec((1, BE), lambda t, *_: (t, 0)),
-            pl.BlockSpec((1, BE), lambda t, *_: (t, 0)),
+            pl.BlockSpec((1, 1, BE), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec((1, 1, BE), lambda t, *_: (t, 0, 0)),
             pl.BlockSpec((3 * H, H), lambda t, *_: (0, 0)),
             pl.BlockSpec((1, H), lambda t, *_: (0, 0)),
             pl.BlockSpec((Lp, H, H), lambda t, *_: (0, 0, 0)),
@@ -399,9 +483,10 @@ def nmp_edge_mlp_agg_bwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
             pl.BlockSpec((1, H), lambda t, *_: (0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, BE, H), x.dtype),                   # xi double-buf
-            pltpu.VMEM((2, BE, H), x.dtype),                   # xj double-buf
-            pltpu.VMEM((2, BE, H), g_agg.dtype),               # g_agg rows
+            pltpu.VMEM((2, BE, x.shape[1]), x.dtype),          # xi double-buf
+            pltpu.VMEM((2, BE, x.shape[1]), x.dtype),          # xj double-buf
+            pltpu.VMEM((2, BE, x.shape[1]), g_agg.dtype),      # g_agg rows
+            pltpu.VMEM((BE, H), f32),                          # scatter rows
             pltpu.VMEM((n_round, H), f32),                     # g_x accum
             pltpu.VMEM((3 * H, H), f32),
             pltpu.VMEM((1, H), f32),
@@ -427,9 +512,10 @@ def nmp_edge_mlp_agg_bwd(x, e_tiles, srcg, dstg, emask, einv, w0, b0, wrest,
             jax.ShapeDtypeStruct((1, H), f32),
             jax.ShapeDtypeStruct((1, H), f32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(srcg, dstg, x, g_agg, e_tiles, emask, einv, w0, b0, wrest, brest,
-      lng, lnb, g_enew)
+    )(srcg, dstg, x, g_agg, e_tiles, emask[:, None], einv[:, None], w0, b0,
+      wrest, brest, lng, lnb, g_enew)
 
 
 def edge_mlp_agg(feats, dst_local, weights, w1, b1, w2, b2, *,

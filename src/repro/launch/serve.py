@@ -26,6 +26,7 @@ import jax
 from repro.core import GNNConfig, box_mesh, partition_mesh
 from repro.core.mesh_gen import taylor_green_velocity
 from repro.ckpt import checkpoint as ckpt
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.runtime.engine import EngineConfig, InferenceEngine
 from repro.train.loop import TrainConfig, train_consistent_gnn
@@ -79,6 +80,7 @@ def main():
             ap.error(f"--{name.replace('_', '-')} must be >= 1, got "
                      f"{getattr(args, name)}")
 
+    enable_compile_cache()
     sem = box_mesh(tuple(int(v) for v in args.mesh.split(",")), p=args.p)
     if not ckpt.committed_steps(args.ckpt_dir):
         if args.bootstrap_steps < 1:

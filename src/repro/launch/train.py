@@ -6,29 +6,29 @@
 
 Uses every substrate layer: SEM mesh gen -> partitioner -> shard_map step
 with real halo collectives -> AdamW -> async checkpoints -> straggler
-monitor. On a real pod, remove the XLA_FLAGS override
-(jax.distributed.initialize picks up the topology).
+monitor. It runs on the devices JAX finds; to run the multi-device grid on
+a CPU host, give it fake host devices yourself:
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 ``--rollout-steps K`` (K > 1) switches to autoregressive rollout training
 (repro.train.rollout): the model is scanned over its own predictions for K
 steps with a per-step halo-consistent loss; ``--pushforward-noise`` adds the
 stop-gradient step-1 perturbation that emulates inference-time drift.
 """
-import os
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import argparse
 
 import numpy as np
 
 from repro.core import GNNConfig, NMPPlan, box_mesh, partition_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.runtime.fault_tolerance import ResilientConfig
 from repro.train.loop import TrainConfig, train_consistent_gnn
 
 
-def main():
+def main(argv=None) -> dict:
+    """Parse ``argv`` (``sys.argv[1:]`` when None), train, print the loss
+    summary and return the training history."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--elements", type=int, nargs=3, default=[4, 4, 2])
     ap.add_argument("--order", type=int, default=3)
@@ -93,7 +93,7 @@ def main():
                     help="stddev of the stop-gradient pushforward noise "
                          "added to the rollout's initial state (emulates "
                          "inference-time drift; needs --rollout-steps > 1)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.rollout_steps < 1:
         ap.error("--rollout-steps must be >= 1")
     if args.pushforward_noise and args.rollout_steps == 1:
@@ -103,6 +103,7 @@ def main():
         ap.error("--ckpt and --ckpt-dir are mutually exclusive (plain "
                  "fire-and-forget saves vs resilient auto-resume)")
 
+    enable_compile_cache()
     sem = box_mesh(tuple(args.elements), p=args.order)
     R = int(np.prod(args.ranks))
     cfg = GNNConfig.small() if args.model == "small" else GNNConfig.large()
@@ -156,6 +157,7 @@ def main():
         print(f"recovered from {hist['restarts']} crash(es)")
     print(f"loss {hist['losses'][0]:.6f} -> {hist['losses'][-1]:.6f} "
           f"({len(hist['losses'])} steps, {hist['straggler_events']} straggler events)")
+    return hist
 
 
 if __name__ == "__main__":

@@ -35,7 +35,10 @@ class ParallelCtx:
 
     @staticmethod
     def single_device() -> "ParallelCtx":
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        # Auto axes: JAX 0.9's make_mesh defaults to Explicit, which puts
+        # the mesh into every array's type and breaks the layer scan
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         return ParallelCtx(mesh=mesh, batch_axes=("data",), rules={})
 
 

@@ -49,14 +49,16 @@ def init_dense(key, d_in: int, d_out: int, dtype=jnp.float32, bias: bool = True)
 def dense(p: Params, x: jnp.ndarray, precision: str | None = None) -> jnp.ndarray:
     """``precision="bf16"`` runs the matmul with bf16 operands accumulating
     into fp32 (``preferred_element_type``) — the same mixed-precision policy
-    the fused Pallas kernels apply; ``None``/``"fp32"`` is the plain path."""
+    the fused Pallas kernels apply; ``None``/``"fp32"`` is a true fp32
+    matmul (``Precision.HIGHEST``: a TPU's default for fp32 operands is a
+    single bf16 pass, which the consistency tolerances do not allow)."""
     if precision == "bf16":
         y = jax.lax.dot_general(
             x.astype(jnp.bfloat16), p["w"].astype(jnp.bfloat16),
             (((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
     else:
-        y = x @ p["w"]
+        y = jnp.matmul(x, p["w"], precision=jax.lax.Precision.HIGHEST)
     if "b" in p:
         y = y + p["b"]
     return y

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import time
 from types import SimpleNamespace
 from typing import Optional
 
@@ -156,7 +157,7 @@ def _init_state(cfg: GNNConfig, tcfg: TrainConfig, opt_cfg: AdamWConfig) -> dict
     return {"params": params, "opt": init_adamw(params, opt_cfg), "rng": key}
 
 
-def _build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy):
+def build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy):
     """Build everything a training step needs for the CURRENT partition:
     plan (halo specs + resolved schedule), ShardedGraph, sharded placement,
     and the per-step grad/update closures.  Shared by the plain and the
@@ -255,7 +256,7 @@ def resume_elastic(ckpt_dir, mesh_dev, pg, sem_mesh, cfg, tcfg, plan):
     """Elastic restore: latest valid checkpoint onto the CURRENT mesh/partition.
 
     The caller has already rebuilt ``PartitionedGraphs`` (+ ``ShardedGraph``
-    + ``NMPPlan`` via :func:`_build_execution`) for the new rank grid —
+    + ``NMPPlan`` via :func:`build_execution`) for the new rank grid —
     block or spectral; this function restores the *portable* state
     (params, opt, rng are partition-independent: replicated over the graph
     axis) onto ``mesh_dev`` via per-leaf shardings, validates the manifest
@@ -375,7 +376,7 @@ def train_consistent_gnn(
     tests/drivers (see ``FaultPlan``); it is only honored on the resilient
     path.
     """
-    ex = _build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy)
+    ex = build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy)
     if tcfg.resilience is not None:
         return _train_resilient(ex, mesh_dev, pg, sem_mesh, cfg, tcfg, fault)
 
@@ -385,13 +386,19 @@ def train_consistent_gnn(
     monitor = StragglerMonitor()
     saver = ckpt.AsyncCheckpointer(tcfg.ckpt_dir) if tcfg.ckpt_dir else None
 
-    history = {"losses": [], "rollout_k": [], "schedule": ex.plan.schedule}
+    history = {"losses": [], "rollout_k": [], "schedule": ex.plan.schedule,
+               "step_seconds": []}
     for step in range(tcfg.n_steps):
+        t0 = time.perf_counter()
         monitor.start_step()
         loss, grads = ex.grad_for_step(params, step)
         params, opt_state, _ = ex.update(params, opt_state, loss, grads)
         monitor.end_step(step)
+        # host clock from dispatch to the loss on the host: step 0 holds the
+        # compile; the device runs update(s-1) before grad(s), so each later
+        # entry covers one gradient and one update
         history["losses"].append(float(loss))
+        history["step_seconds"].append(time.perf_counter() - t0)
         history["rollout_k"].append(ex.k_for_step(step))
         if saver and (step % tcfg.ckpt_every == 0 or step == tcfg.n_steps - 1):
             # same tree + fingerprinted manifest as the resilient path, so

@@ -64,6 +64,25 @@ def _rollout(mesh, cfg, params, grid, mode, schedule, noise_global=None):
     return float(loss), preds_g, grads
 
 
+def _assert_preds_consistent(p, p_ref):
+    """Per-step predictions agree up to fp32 summation order.
+
+    Partitioning only reorders the fp32 sums of each node's aggregate, and
+    that rounding is relative to the magnitude of the field being summed,
+    not to each output element: an element that happens to sit near zero
+    carries the same absolute error as its neighbours. So step k is bounded
+    in the max norm relative to the field's max norm, with a bound that
+    grows 3x per step because each step feeds its rounding back into the
+    next. (Measured on the (4, 2, 2) p=2 mesh: 5e-7, 4e-6, 1.2e-5 for
+    k = 1, 2, 3; dropping the halo exchange gives errors as large as the
+    field itself.)
+    """
+    for k in range(p_ref.shape[0]):
+        scale = float(np.abs(p_ref[k]).max())
+        err = float(np.abs(p[k] - p_ref[k]).max())
+        assert err <= 1e-5 * 3 ** k * scale, (k, err, scale)
+
+
 def _grad_rel_err(a, b):
     na = float(jnp.sqrt(sum(jnp.sum(jnp.square(x))
                             for x in jax.tree.leaves(a))))
@@ -81,7 +100,7 @@ def test_rollout_consistency_1_vs_4_ranks(schedule, grid):
     l1, p1, g1 = _rollout(mesh, cfg, params, (1, 1, 1), NONE, schedule)
     l4, p4, g4 = _rollout(mesh, cfg, params, grid, A2A, schedule)
     assert abs(l4 - l1) < 2e-6 * max(1.0, abs(l1)), (grid, schedule)
-    np.testing.assert_allclose(p4, p1, rtol=3e-4, atol=1e-5)
+    _assert_preds_consistent(p4, p1)
     # K chained forwards amplify fp32 summation-order noise elementwise, so
     # gradients are compared by relative norm (loss/value agreement above is
     # the bitwise-level check)
@@ -95,7 +114,7 @@ def test_rollout_blocking_matches_overlap():
     lb, pb, gb = _rollout(mesh, cfg, params, (2, 2, 1), A2A, "blocking")
     lo, po, go = _rollout(mesh, cfg, params, (2, 2, 1), A2A, "overlap")
     assert abs(lo - lb) < 1e-6 * max(1.0, abs(lb))
-    np.testing.assert_allclose(po, pb, rtol=3e-4, atol=1e-5)
+    _assert_preds_consistent(po, pb)
     assert _grad_rel_err(gb, go) < 5e-4
 
 
@@ -121,7 +140,7 @@ def test_pushforward_noise_consistent_and_stop_grad():
     l4, p4, g4 = _rollout(mesh, cfg, params, (2, 2, 1), A2A, "blocking",
                           noise_global=nz)
     assert abs(l4 - l1) < 2e-6 * max(1.0, abs(l1))
-    np.testing.assert_allclose(p4, p1, rtol=3e-4, atol=1e-5)
+    _assert_preds_consistent(p4, p1)
     assert _grad_rel_err(g1, g4) < 5e-4
     # the noise engaged
     l0, _, _ = _rollout(mesh, cfg, params, (1, 1, 1), NONE, "blocking")

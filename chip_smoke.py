@@ -202,7 +202,7 @@ def shard_map_vs_stacked(seed: int) -> dict:
             "grad_max_abs_err": err}
 
 
-def fused_vs_xla(seed: int, interpret: bool = False) -> dict:
+def fused_vs_xla(seed: int) -> dict:
     """Compiled fused Pallas backend == XLA backend, values and gradients,
     R=1 and R=4 under the blocking schedule and R=4 under overlap."""
     import jax.numpy as jnp
@@ -217,8 +217,7 @@ def fused_vs_xla(seed: int, interpret: bool = False) -> dict:
                                  ((2, 2, 1), A2A, "overlap")):
         pg = partition_mesh(sem, grid)
         plan_f = NMPPlan(halo=HaloSpec(mode=mode), backend="fused",
-                         schedule=schedule, interpret=interpret
-                         ).autotune_blocks(cfg.hidden)
+                         schedule=schedule).autotune_blocks(cfg.hidden)
         plan_x = plan_f.replace(backend="xla")
         graph = ShardedGraph.build(pg, sem.coords, plan_f)
         x = jnp.asarray(gather_node_features(pg, x_global))
@@ -238,7 +237,7 @@ def fused_vs_xla(seed: int, interpret: bool = False) -> dict:
     return out
 
 
-def packed_vs_dense(seed: int, interpret: bool = False) -> dict:
+def packed_vs_dense(seed: int) -> dict:
     """Packed halo wire (Pallas pack/unpack) vs the dense exchange.
 
     The exchange alone is pure data movement and must be bitwise equal,
@@ -263,7 +262,7 @@ def packed_vs_dense(seed: int, interpret: bool = False) -> dict:
     x = jnp.asarray(gather_node_features(pg, x_global))
     out = {}
 
-    plan_p = NMPPlan.build(pg, NEIGHBOR, packed=True, interpret=interpret)
+    plan_p = NMPPlan.build(pg, NEIGHBOR, packed=True)
     graph = ShardedGraph.build(pg, sem.coords, plan_p)
     rng = np.random.default_rng(seed)
     a = jnp.asarray(rng.normal(size=(pg.R, pg.n_pad, cfg.hidden)),
@@ -285,10 +284,8 @@ def packed_vs_dense(seed: int, interpret: bool = False) -> dict:
     out["exchange_max_abs_err"] = err
 
     for schedule in ("blocking", "overlap"):
-        plan_p = NMPPlan.build(pg, NEIGHBOR, packed=True, schedule=schedule,
-                               interpret=interpret)
-        plan_d = NMPPlan.build(pg, NEIGHBOR, packed=False, schedule=schedule,
-                               interpret=interpret)
+        plan_p = NMPPlan.build(pg, NEIGHBOR, packed=True, schedule=schedule)
+        plan_d = NMPPlan.build(pg, NEIGHBOR, packed=False, schedule=schedule)
         graph = ShardedGraph.build(pg, sem.coords, plan_p)
 
         def fwd_and_grad(p, x, graph, plan):

@@ -12,6 +12,11 @@ Layer structure follows the paper exactly:
   4d  a_i*  = sum over coincident copies       (fused scatter-add)
   4e  x_i'  = MLP_n(a_i*, x_i)                 (residual on node features)
 
+Every layer implementation names its parts on the device with
+``jax.named_scope``: ``edge_agg`` (4a-b), ``halo`` (4c-d, with the
+edge-parallel psum) and ``node`` (4e); the V-cycle names each level's
+restriction, smoothing and prolongation ``vcycle/l{k}``.
+
 Execution policy comes from one :class:`~repro.core.graph_state.NMPPlan`;
 graph state from one :class:`~repro.core.graph_state.ShardedGraph`.  The
 four (backend x schedule) layer implementations register themselves in the
@@ -246,20 +251,21 @@ def node_update(params: nn.Params, x: jnp.ndarray, agg: jnp.ndarray,
 def _blocking_layer(agg_fn, params, x, e, graph, plan, halo, sync_fn,
                     edge_parallel_axes):
     """The paper's serial order: full Eq. 4a+4b, exchange, Eq. 4e."""
-    e_new, agg = agg_fn(params, x, e, graph, plan)
-    if edge_parallel_axes:
-        # combine partial aggregates in the activation dtype (halves wire
-        # bytes when activations are bf16)
-        agg = jax.lax.psum(agg.astype(e.dtype), edge_parallel_axes)
-
-    # --- Eq. 4c + 4d: halo swap + synchronization ---
-    if sync_fn is not None:
-        agg = sync_fn(agg)
-    else:
-        agg = halo_sync(agg, graph, halo, combine="sum")
-
+    with jax.named_scope("edge_agg"):
+        e_new, agg = agg_fn(params, x, e, graph, plan)
+    with jax.named_scope("halo"):
+        if edge_parallel_axes:
+            # combine partial aggregates in the activation dtype (halves
+            # wire bytes when activations are bf16)
+            agg = jax.lax.psum(agg.astype(e.dtype), edge_parallel_axes)
+        # --- Eq. 4c + 4d: halo swap + synchronization ---
+        if sync_fn is not None:
+            agg = sync_fn(agg)
+        else:
+            agg = halo_sync(agg, graph, halo, combine="sum")
     # --- Eq. 4e: node update (residual) ---
-    return node_update(params, x, agg, graph), e_new
+    with jax.named_scope("node"):
+        return node_update(params, x, agg, graph), e_new
 
 
 def _overlap_layer(agg_part_fn, params, x, e, graph, plan, halo, sync_fn,
@@ -268,20 +274,26 @@ def _overlap_layer(agg_part_fn, params, x, e, graph, plan, halo, sync_fn,
     partial aggregate; interior-edge compute has no data dependence on the
     collective and overlaps the in-flight ppermute rounds."""
     # boundary side first — the exchange consumes its aggregate
-    e_bnd, agg_bnd = agg_part_fn(params, x, e, graph, "bnd", plan)
-    if edge_parallel_axes:
-        agg_bnd = jax.lax.psum(agg_bnd.astype(e.dtype), edge_parallel_axes)
-    # --- Eq. 4c + 4d on the boundary rows only ---
-    if sync_fn is not None:
-        agg_sync = sync_fn(agg_bnd)
-    else:
-        agg_sync = halo_sync(agg_bnd, graph, halo, combine="sum")
+    with jax.named_scope("edge_agg"):
+        e_bnd, agg_bnd = agg_part_fn(params, x, e, graph, "bnd", plan)
+    with jax.named_scope("halo"):
+        if edge_parallel_axes:
+            agg_bnd = jax.lax.psum(agg_bnd.astype(e.dtype), edge_parallel_axes)
+        # --- Eq. 4c + 4d on the boundary rows only ---
+        if sync_fn is not None:
+            agg_sync = sync_fn(agg_bnd)
+        else:
+            agg_sync = halo_sync(agg_bnd, graph, halo, combine="sum")
     # interior side: independent of the collective -> overlappable
-    e_int, agg_int = agg_part_fn(params, x, e, graph, "int", plan)
+    with jax.named_scope("edge_agg"):
+        e_int, agg_int = agg_part_fn(params, x, e, graph, "int", plan)
+        e_new = e_bnd + e_int
     if edge_parallel_axes:
-        agg_int = jax.lax.psum(agg_int.astype(e.dtype), edge_parallel_axes)
-    agg = agg_sync + agg_int          # disjoint row support
-    return node_update(params, x, agg, graph), e_bnd + e_int
+        with jax.named_scope("halo"):
+            agg_int = jax.lax.psum(agg_int.astype(e.dtype), edge_parallel_axes)
+    with jax.named_scope("node"):
+        agg = agg_sync + agg_int          # disjoint row support
+        return node_update(params, x, agg, graph), e_new
 
 
 for _backend, _agg in _AGGS.items():
@@ -420,26 +432,28 @@ def multilevel_vcycle(
     states = [h]
     # --- down sweep: restrict, complete partial sums, smooth ---
     for lvl in range(1, n_levels):
-        g = levels[lvl]
-        n_pad_c = g["node_mask"].shape[-1]
-        c = restrict_aggregate(states[-1], g, n_pad_c)
-        c = sync(c, lvl, g) * g["node_mask"][..., None]
-        p = coarse_params[lvl - 1]
-        e = nn.mlp(p["edge_enc"], g["static_edge_feats"]) \
-            * g["edge_mask"][..., None]
-        if c.ndim == 3:
-            e = jnp.broadcast_to(e[None], (c.shape[0],) + e.shape)
-        for lp in p["mp"]:
-            c, e = nmp_layer(lp, c, e, g, plan, halo=halos[lvl],
-                             sync_fn=sync_fns[lvl] if sync_fns else None)
-        states.append(c)
+        with jax.named_scope(f"vcycle/l{lvl}"):
+            g = levels[lvl]
+            n_pad_c = g["node_mask"].shape[-1]
+            c = restrict_aggregate(states[-1], g, n_pad_c)
+            c = sync(c, lvl, g) * g["node_mask"][..., None]
+            p = coarse_params[lvl - 1]
+            e = nn.mlp(p["edge_enc"], g["static_edge_feats"]) \
+                * g["edge_mask"][..., None]
+            if c.ndim == 3:
+                e = jnp.broadcast_to(e[None], (c.shape[0],) + e.shape)
+            for lp in p["mp"]:
+                c, e = nmp_layer(lp, c, e, g, plan, halo=halos[lvl],
+                                 sync_fn=sync_fns[lvl] if sync_fns else None)
+            states.append(c)
     # --- up sweep: prolong, complete partial sums, residual add ---
     for lvl in range(n_levels - 1, 0, -1):
-        gf = levels[lvl - 1]
-        n_pad_f = gf["node_mask"].shape[-1]
-        up = prolong_aggregate(states[lvl], levels[lvl], n_pad_f)
-        up = sync(up, lvl - 1, gf)
-        states[lvl - 1] = (states[lvl - 1] + up) * gf["node_mask"][..., None]
+        with jax.named_scope(f"vcycle/l{lvl}"):
+            gf = levels[lvl - 1]
+            n_pad_f = gf["node_mask"].shape[-1]
+            up = prolong_aggregate(states[lvl], levels[lvl], n_pad_f)
+            up = sync(up, lvl - 1, gf)
+            states[lvl - 1] = (states[lvl - 1] + up) * gf["node_mask"][..., None]
     return states[0]
 
 

@@ -22,6 +22,7 @@ from typing import Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.consistent_loss import consistent_mse
 from repro.core.gnn import GNNConfig, gnn_forward
 from repro.core.graph_state import NMPPlan, as_graph
@@ -60,10 +61,11 @@ def make_gnn_step_fns(
         x, y_hat = x[:, 0], y_hat[:, 0]
         y = gnn_forward(params, x, g, plan)
         # consistent over the graph axis (Eq. 6), mean over data axes
-        loss = consistent_mse(y, y_hat, g["node_inv_mult"],
-                              axis_names=(graph_axis,))
-        if data_axes:
-            loss = jax.lax.pmean(loss, tuple(data_axes))
+        with jax.named_scope("loss"):
+            loss = consistent_mse(y, y_hat, g["node_inv_mult"],
+                                  axis_names=(graph_axis,))
+            if data_axes:
+                loss = jax.lax.pmean(loss, tuple(data_axes))
         return loss, y
 
     def grad_local(params, x, y_hat, graph):
@@ -73,7 +75,8 @@ def make_gnn_step_fns(
         # d(sum over ALL devices of the replicated scalar)/d theta_q
         # = n_dev * dL/d theta_q  (theta paths local to q, incl. halo routes).
         # pmean over every axis therefore yields exactly dL/d theta.
-        grads = jax.tree.map(lambda g: jax.lax.pmean(g, all_axes), grads)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree.map(lambda g: jax.lax.pmean(g, all_axes), grads)
         return loss, grads
 
     def _wrap(fn, out_specs, n_feature_args):
@@ -116,7 +119,7 @@ def make_gnn_step_fns(
         return jax.jit(call, donate_argnums=(0,) if donate else ())
 
     train_step = _wrap_pair(train_local, donate=True)
-    grad_step = _wrap_pair(grad_local)
+    grad_step = obs.program("grad_step", _wrap_pair(grad_local))
 
     return eval_step, loss_step, grad_step, train_step
 

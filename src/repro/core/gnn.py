@@ -4,9 +4,17 @@
   2) M consistent NMP layers (Sec. II-B);
   3) node decoder: local MLP N_H -> F_y (edge features discarded).
 
-Configs: "small" (N_H=8, M=4, 2 MLP hidden layers, 3,979 params) and
-"large" (N_H=32, M=4, 5 MLP hidden layers, 91,459 params) with F_x=3
-(velocity), F_e=7 (relative velocity + distance vector + magnitude).
+Presets: ``small()`` (N_H=8, M=4, ``mlp_hidden_layers`` 2: 3,211 params)
+and ``large()`` (N_H=32, M=4, ``mlp_hidden_layers`` 5: 79,939 params), with
+F_x=3 (velocity) and F_e=7 (relative velocity + distance vector +
+magnitude). Each builds one hidden-to-hidden layer fewer in every MLP than
+Table I: ``mlp_hidden_layers`` 3 and 6 build Table I's depth, 4,003 and
+91,555 params, which are Table I's 3,979 and 91,459 with the 7 edge inputs
+in place of the 4 that Table I's counts imply (3 * N_H more weights).
+
+The forward pass names its layers on the device with ``jax.named_scope``:
+``enc``, ``nmp{i}`` around each NMP layer, and ``dec`` (the vocabulary is
+in ``repro.obs``).
 
 ``GNNConfig`` is pure architecture; the execution policy (backend,
 schedule, precision, halo specs, ...) lives in one
@@ -115,12 +123,15 @@ def gnn_forward(
     """
     graph = as_graph(graph)
     g0 = graph.levels[0]
-    e_in = build_edge_inputs(x, g0)
-    h = nn.mlp(params["node_enc"], x) * g0["node_mask"][..., None]
-    e = nn.mlp(params["edge_enc"], e_in) * g0["edge_mask"][..., None]
-    for lp in params["mp"]:
-        h, e = nmp_layer(lp, h, e, g0, plan)
+    with jax.named_scope("enc"):
+        e_in = build_edge_inputs(x, g0)
+        h = nn.mlp(params["node_enc"], x) * g0["node_mask"][..., None]
+        e = nn.mlp(params["edge_enc"], e_in) * g0["edge_mask"][..., None]
+    for i, lp in enumerate(params["mp"]):
+        with jax.named_scope(f"nmp{i}"):
+            h, e = nmp_layer(lp, h, e, g0, plan)
     if "coarse" in params:
         h = multilevel_vcycle(params["coarse"], h, graph, plan)
-    y = nn.mlp(params["node_dec"], h) * g0["node_mask"][..., None]
+    with jax.named_scope("dec"):
+        y = nn.mlp(params["node_dec"], h) * g0["node_mask"][..., None]
     return y

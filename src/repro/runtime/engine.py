@@ -25,6 +25,11 @@ identical to single-rank inference.  This module is that serving path:
   :meth:`InferenceEngine.stream` wires a multi-producer
   ``PrefetchingLoader`` (the repo's hang-safe transport) in front of the
   queue for solver-style feeds.
+* Each request's host work is recorded as ``repro.obs`` spans with the
+  request's sequence number as id: ``engine/queue_wait`` (submit to
+  dequeue), ``engine/gather``, ``engine/predict`` (the batch's placement,
+  device run and read-back, under its first request) and
+  ``engine/scatter``.
 
 Consistency contract (asserted in-process by ``tests/test_engine.py`` and
 on real collectives by ``tests/drivers/serve_driver.py`` under the CI
@@ -49,6 +54,7 @@ committed step, like the resilient trainer.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -58,6 +64,7 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.ckpt import checkpoint as ckpt
 from repro.core import GNNConfig, NMPPlan, init_gnn, partition_mesh
 from repro.core.distributed import shard_graph
@@ -147,6 +154,7 @@ class RequestFuture:
 
 @dataclasses.dataclass
 class _Request:
+    seq: int                   # the engine's request number: its spans' id
     step: int
     key: tuple
     x: np.ndarray              # global [N, F] snapshot
@@ -162,7 +170,11 @@ class _GraphEntry:
     plan: NMPPlan
     gs: ShardedGraph
     predict: Callable
-    build_s: float
+
+
+def _dequeued(req: _Request) -> _Request:
+    obs.record("engine/queue_wait", req.t_submit, time.perf_counter(), req.seq)
+    return req
 
 
 class InferenceEngine:
@@ -198,6 +210,7 @@ class InferenceEngine:
         self._stop = threading.Event()
         self._failure: Optional[BaseException] = None
         self._thread: Optional[threading.Thread] = None
+        self._seq = itertools.count()
         self.stats = {"requests": 0, "batches": 0, "padded_slots": 0,
                       "cache_hits": 0, "cache_builds": 0}
 
@@ -273,7 +286,6 @@ class InferenceEngine:
             if key in self._graphs:
                 self.stats["cache_hits"] += 1
                 return mesh_hash
-            t0 = time.perf_counter()
             grid = tuple(rank_grid) if rank_grid is not None \
                 else (self.R, 1, 1)
             if int(np.prod(grid)) != self.R:
@@ -294,7 +306,7 @@ class InferenceEngine:
                 self.mesh_dev, self.cfg, plan, self.config.rollout_steps)
             self._graphs[key] = _GraphEntry(
                 mesh_hash=mesh_hash, pg=pg, plan=plan, gs=gs,
-                predict=predict, build_s=time.perf_counter() - t0)
+                predict=predict)
             self.stats["cache_builds"] += 1
         return mesh_hash
 
@@ -392,7 +404,7 @@ class InferenceEngine:
                 f"snapshot shape {tuple(x.shape)} does not match the "
                 f"registered mesh ({want[0]} nodes x {want[1]} fields)")
         fut = RequestFuture(step)
-        req = _Request(step=step,
+        req = _Request(seq=next(self._seq), step=step,
                        key=(mesh_hash, partitioner or self.config.partitioner),
                        x=x, future=fut, t_submit=time.perf_counter())
         try:
@@ -472,6 +484,7 @@ class InferenceEngine:
                     first = self._q.get(timeout=0.05)
                 except queue.Empty:
                     continue
+                _dequeued(first)
                 batch = [first]
                 deadline = time.perf_counter() + self.config.flush_timeout_s
                 while len(batch) < self.config.batch_slots:
@@ -479,7 +492,7 @@ class InferenceEngine:
                     if rem <= 0:
                         break
                     try:
-                        batch.append(self._q.get(timeout=rem))
+                        batch.append(_dequeued(self._q.get(timeout=rem)))
                     except queue.Empty:
                         break
                 # group by graph-cache key: multi-geometry ready (today all
@@ -500,16 +513,21 @@ class InferenceEngine:
         entry = self._graphs[key]
         slots = self.config.batch_slots
         try:
-            xs = [gather_node_features(entry.pg, r.x) for r in reqs]
+            xs = []
+            for r in reqs:
+                with obs.span("engine/gather", r.seq):
+                    xs.append(gather_node_features(entry.pg, r.x))
             n_pad = slots - len(xs)
             xs.extend(np.zeros_like(xs[0]) for _ in range(n_pad))
-            preds = np.asarray(
-                entry.predict(self.params, np.stack(xs), entry.gs))
+            with obs.span("engine/predict", reqs[0].seq):
+                preds = np.asarray(
+                    entry.predict(self.params, np.stack(xs), entry.gs))
             t_done = time.perf_counter()
             for i, r in enumerate(reqs):
-                out = np.stack([
-                    scatter_node_outputs(entry.pg, preds[i, k])
-                    for k in range(self.config.rollout_steps)])
+                with obs.span("engine/scatter", r.seq):
+                    out = np.stack([
+                        scatter_node_outputs(entry.pg, preds[i, k])
+                        for k in range(self.config.rollout_steps)])
                 r.future._set(InferenceResult(
                     step=r.step, mesh_hash=key[0], preds=out,
                     latency_s=t_done - r.t_submit))

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.distributed import make_gnn_step_fns, shard_graph
 from repro.core.gnn import GNNConfig, init_gnn
 from repro.core.graph_state import AUTO, BLOCKING, OVERLAP, NMPPlan, ShardedGraph
@@ -162,7 +163,9 @@ def build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy):
     plan (halo specs + resolved schedule), ShardedGraph, sharded placement,
     and the per-step grad/update closures.  Shared by the plain and the
     resilient paths — an elastic resume simply rebuilds this for the new
-    rank grid and restores params/opt into it."""
+    rank grid and restores params/opt into it.  Each step's batch, built on
+    the host and placed, is the ``repro.obs`` span ``train/batch`` (id: the
+    step); the optimizer step is the program ``update``."""
     if cfg.n_levels > 1 and hierarchy is None:
         raise ValueError("cfg.n_levels > 1 needs hierarchy= "
                          "(repro.core.coarsen.build_hierarchy)")
@@ -200,6 +203,7 @@ def build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy):
     @jax.jit
     def update(params, opt_state, loss, grads):
         return adamw_update(grads, opt_state, params, opt_cfg)
+    update = obs.program("update", update)
 
     # the static graph is loop-invariant: place it once, not per step
     gs = shard_graph(mesh_dev, graph)
@@ -231,10 +235,11 @@ def build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy):
                     noise_scale=noise_scale, seed=tcfg.seed)
                 fns_by_k[k] = (rollout_grad, bf)
             rollout_grad, batch_fn = fns_by_k[k]
-            x0, targets, noise = batch_fn(step)
-            xs = jax.device_put(jnp.asarray(x0), feat_sh)
-            ts = jax.device_put(jnp.asarray(targets), seq_sh)
-            ns = jax.device_put(jnp.asarray(noise), feat_sh)
+            with obs.span("train/batch", step):
+                x0, targets, noise = batch_fn(step)
+                xs = jax.device_put(jnp.asarray(x0), feat_sh)
+                ts = jax.device_put(jnp.asarray(targets), seq_sh)
+                ns = jax.device_put(jnp.asarray(noise), feat_sh)
             return rollout_grad(params, xs, ts, ns, gs)
     else:
         _, _, grad_step, _ = make_gnn_step_fns(mesh_dev, cfg, plan)
@@ -244,7 +249,8 @@ def build_execution(mesh_dev, pg, sem_mesh, cfg, tcfg, hierarchy):
             return 1
 
         def grad_for_step(params, step):
-            xs = jax.device_put(jnp.asarray(batch_fn(step)), feat_sh)
+            with obs.span("train/batch", step):
+                xs = jax.device_put(jnp.asarray(batch_fn(step)), feat_sh)
             return grad_step(params, xs, xs, gs)
 
     return SimpleNamespace(plan=plan, graph=graph, gs=gs, opt_cfg=opt_cfg,

@@ -84,37 +84,39 @@ def clip_by_global_norm(grads, max_norm: float):
 
 
 def adamw_update(grads, state, params, cfg: AdamWConfig):
-    """One AdamW step. params/grads may be lower precision; math in fp32."""
-    step = state["step"] + 1
-    lr = cfg.schedule(step)
-    if cfg.clip_norm is not None:
-        grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
-    else:
-        gnorm = rnn.global_norm(grads)
-    mask = _decay_mask(params, cfg)
+    """One AdamW step. params/grads may be lower precision; math in fp32.
+    Its ops carry the device scope ``adamw``."""
+    with jax.named_scope("adamw"):
+        step = state["step"] + 1
+        lr = cfg.schedule(step)
+        if cfg.clip_norm is not None:
+            grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
+        else:
+            gnorm = rnn.global_norm(grads)
+        mask = _decay_mask(params, cfg)
 
-    b1, b2 = cfg.b1, cfg.b2
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
+        b1, b2 = cfg.b1, cfg.b2
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v, dmask):
-        g32 = g.astype(jnp.float32)
-        m32 = m.astype(jnp.float32) * b1 + (1 - b1) * g32
-        v32 = v.astype(jnp.float32) * b2 + (1 - b2) * jnp.square(g32)
-        mhat = m32 / bc1
-        vhat = v32 / bc2
-        step_vec = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        p32 = p.astype(jnp.float32)
-        p32 = p32 - lr * (step_vec + cfg.weight_decay * dmask * p32)
-        return p32.astype(p.dtype), m32.astype(cfg.moment_dtype), v32.astype(cfg.moment_dtype)
+        def upd(p, g, m, v, dmask):
+            g32 = g.astype(jnp.float32)
+            m32 = m.astype(jnp.float32) * b1 + (1 - b1) * g32
+            v32 = v.astype(jnp.float32) * b2 + (1 - b2) * jnp.square(g32)
+            mhat = m32 / bc1
+            vhat = v32 / bc2
+            step_vec = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            p32 = p.astype(jnp.float32)
+            p32 = p32 - lr * (step_vec + cfg.weight_decay * dmask * p32)
+            return p32.astype(p.dtype), m32.astype(cfg.moment_dtype), v32.astype(cfg.moment_dtype)
 
-    out = jax.tree.map(upd, params, grads, state["m"], state["v"], mask)
-    # unzip the 3-tuples
-    new_params = jax.tree.map(lambda t: t[0], out, is_leaf=lambda t: isinstance(t, tuple))
-    new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda t: isinstance(t, tuple))
-    new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda t: isinstance(t, tuple))
-    new_state = {"m": new_m, "v": new_v, "step": step}
-    return new_params, new_state, {"lr": lr, "grad_norm": gnorm}
+        out = jax.tree.map(upd, params, grads, state["m"], state["v"], mask)
+        # unzip the 3-tuples
+        new_params = jax.tree.map(lambda t: t[0], out, is_leaf=lambda t: isinstance(t, tuple))
+        new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda t: isinstance(t, tuple))
+        new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda t: isinstance(t, tuple))
+        new_state = {"m": new_m, "v": new_v, "step": step}
+        return new_params, new_state, {"lr": lr, "grad_norm": gnorm}
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.consistent_loss import consistent_mse
 from repro.core.gnn import GNNConfig, gnn_forward
 from repro.core.graph_state import NMPPlan, as_graph
@@ -86,8 +87,9 @@ def rollout_step(params, x0, targets, graph, plan: NMPPlan,
 
     def body(carry, tgt):
         y = gnn_forward(params, carry, graph, plan)
-        loss_k = consistent_mse(y, tgt, g0["node_inv_mult"],
-                                axis_names=axis_names)
+        with jax.named_scope("loss"):
+            loss_k = consistent_mse(y, tgt, g0["node_inv_mult"],
+                                    axis_names=axis_names)
         return y, (loss_k, y)
 
     _, (losses, preds) = jax.lax.scan(body, x, targets)
@@ -121,14 +123,16 @@ def make_rollout_step_fns(
                                    noise=noise[:, 0],
                                    axis_names=(graph_axis,))
         if data_axes:
-            loss = jax.lax.pmean(loss, tuple(data_axes))
+            with jax.named_scope("loss"):
+                loss = jax.lax.pmean(loss, tuple(data_axes))
         # preds [K, B, N_pad, F] -> [B, K, 1, N_pad, F]
         return loss, jnp.moveaxis(preds, 0, 1)[:, :, None]
 
     def grad_local(params, x0, targets, noise, graph):
         (loss, _), grads = jax.value_and_grad(rollout_local, has_aux=True)(
             params, x0, targets, noise, graph)
-        grads = jax.tree.map(lambda g: jax.lax.pmean(g, all_axes), grads)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree.map(lambda g: jax.lax.pmean(g, all_axes), grads)
         return loss, grads
 
     feat = P(tuple(data_axes), graph_axis, None, None)
@@ -179,6 +183,7 @@ def make_rollout_predict_fn(
     """
     rollout_eval, _ = make_rollout_step_fns(
         mesh, cfg, plan, rollout_steps, data_axes, graph_axis)
+    rollout_eval = obs.program("rollout_predict", rollout_eval)
     feat_sh = NamedSharding(mesh, P(tuple(data_axes), graph_axis, None, None))
     seq_sh = NamedSharding(mesh, P(tuple(data_axes), None, graph_axis,
                                    None, None))

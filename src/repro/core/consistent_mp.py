@@ -25,7 +25,10 @@ four (backend x schedule) layer implementations register themselves in the
 Backends for the 4a+4b hot loop (``plan.backend``):
 
 * ``"xla"``   — plain lowering: HBM-materialized ``[E, 3H]`` gather+concat,
-  edge MLP, then a serialized ``segment_sum`` scatter-add.  Always available.
+  edge MLP, then the ``segment_sum``.  Always available.  On a graph that
+  carries per-node slot tables (bounded degree, see
+  ``PartitionedGraphs.slot_tables``) the sum and the gathers' transposes
+  are gathers through those tables; otherwise they are scatter-adds.
 * ``"fused"`` — the Pallas kernel pair in ``repro.kernels.segment_agg``:
   per-tile src/dst node-id lists are scalar-prefetched into SMEM and drive
   double-buffered DMA row gathers of node features out of HBM/ANY memory;
@@ -115,17 +118,26 @@ def _agg_xla(params, x, e, graph: ShardedGraph, plan: NMPPlan):
     src = graph["edge_src"]
     dst = graph["edge_dst"]
     n_pad = x.shape[-2]
+    # a bounded-degree graph carries per-node slot tables: every gather and
+    # sum below, and their transposes, are then gathers (no scatter-add)
+    slots = "in_slots" in graph
 
     # --- Eq. 4a: edge update (residual) ---
-    xi = segment.gather(x, src)
-    xj = segment.gather(x, dst)
+    if slots:
+        xi = segment.slot_gather(x, src, graph["out_slots"])
+        xj = segment.slot_gather(x, dst, graph["in_slots"])
+    else:
+        xi = segment.gather(x, src)
+        xj = segment.gather(x, dst)
     feats = jnp.concatenate([xi, xj, e], axis=-1)
     e_new = e + nn.mlp(params["edge"], feats, precision=_mlp_precision(plan))
     e_new = e_new * graph["edge_mask"][..., None]
 
     # --- Eq. 4b: local aggregation with inverse edge multiplicity ---
     weighted = e_new * graph["edge_inv_mult"][..., None]
-    if x.ndim == 3:
+    if slots:
+        agg = segment.slot_segment_sum(weighted, dst, graph["in_slots"])
+    elif x.ndim == 3:
         agg = jax.vmap(lambda w: segment.segment_sum(w, dst, n_pad))(weighted)
     else:
         agg = segment.segment_sum(weighted, dst, n_pad)

@@ -9,7 +9,8 @@ replace that plumbing:
 * :class:`ShardedGraph` — a registered pytree bundling the per-rank static
   arrays of one partition level (node/edge indices, masks, inverse
   multiplicities, halo exchange buffers, static geometric edge features,
-  the fused-kernel segment layouts and the interior/boundary split), with
+  the per-node edge slot tables of a bounded-degree graph, the fused-kernel
+  segment layouts and the interior/boundary split), with
   each coarser level of a multilevel hierarchy nested as a child
   ``ShardedGraph`` carrying its restriction/prolongation transfer maps.
   Because it is a pytree, the whole graph flows through ``jit`` /
@@ -437,6 +438,8 @@ def _level_arrays(pg, coords, seg_layout, split,
               for k, v in pg.device_arrays(seg_layout=seg_layout,
                                            split=split,
                                            packed=packed).items()}
+    arrays.update((k, jnp.asarray(v))
+                  for k, v in (pg.slot_tables() or {}).items())
     coords_r = gather_node_features(pg, coords)
     ef = []
     for r in range(pg.R):

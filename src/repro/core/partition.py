@@ -105,6 +105,10 @@ class PartitionedGraphs:
     # bucketed per-round packed halo arrays, memoized per bucket size
     _packed_halos: Dict[int, dict] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    # per-node edge slot tables for the gather-only Eq. 4a-b, memoized
+    # ({} once the rank graphs are found not bounded-degree)
+    _slots: dict | None = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     @property
     def n_pad(self) -> int:
@@ -225,6 +229,35 @@ class PartitionedGraphs:
         self._seg_layouts[key] = layout
         return layout
 
+    def slot_tables(self) -> Dict[str, np.ndarray] | None:
+        """Cached per-node edge slot tables: the inverse maps of
+        ``edge_dst`` / ``edge_src`` that let Eq. 4a-b sum and gather with no
+        scatter (``repro.graph.segment.slot_segment_sum``).
+
+        ``in_slots`` [R, N_pad, D] int32 lists, for each local node, the ids
+        of the real edges whose dst is that node, in edge order;
+        ``out_slots`` the same by src.  ``D`` is the largest in- or
+        out-degree over ranks; padding slots hold ``E_pad``, an id that reads
+        zero.  Edges with ``edge_mask`` 0 are left out of both tables.
+
+        None where the rank graphs have no real edge, or are not of bounded
+        degree, i.e. the tables would hold more than ``SLOT_FILL`` slots per
+        padded edge (``N_pad * D > SLOT_FILL * E_pad``): a hub would make
+        every row as wide as its degree.
+        """
+        if self._slots is None:
+            keep = self.edge_mask > 0
+            counts = [_degrees(ids, keep, self.n_pad)
+                      for ids in (self.edge_dst, self.edge_src)]
+            width = max(int(c.max(initial=0)) for c in counts)
+            self._slots = {}
+            if 0 < width and self.n_pad * width <= SLOT_FILL * self.e_pad:
+                for name, ids, c in zip(("in_slots", "out_slots"),
+                                        (self.edge_dst, self.edge_src), counts):
+                    self._slots[name] = _slot_table(ids, keep, c, width,
+                                                    self.e_pad)
+        return self._slots or None
+
     def packed_halo(self, bucket: int = 8) -> Dict[str, np.ndarray]:
         """Cached bucketed per-round packed halo arrays (the packed wire
         format — see :func:`packed_halo_arrays`).  One dict entry set per
@@ -341,6 +374,32 @@ class PartitionedGraphs:
 # ---------------------------------------------------------------------------
 # element partitioning (NekRS-style decompositions)
 # ---------------------------------------------------------------------------
+
+#: most slots per padded edge for which a rank graph gets slot tables
+SLOT_FILL = 1.25
+
+
+def _degrees(ids: np.ndarray, keep: np.ndarray, n_pad: int) -> np.ndarray:
+    """[R * n_pad] count of kept edges per (rank, node id)."""
+    r, e = np.nonzero(keep)
+    return np.bincount(r * n_pad + ids[r, e], minlength=ids.shape[0] * n_pad)
+
+
+def _slot_table(ids: np.ndarray, keep: np.ndarray, counts: np.ndarray,
+                width: int, e_pad: int) -> np.ndarray:
+    """[R, n_pad, width] ids of the kept edges of each (rank, node id), in
+    edge order, padded with ``e_pad``."""
+    R = ids.shape[0]
+    n_pad = counts.size // R
+    r, e = np.nonzero(keep)                  # rank-major, edge order
+    key = r * n_pad + ids[r, e]
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    start = np.cumsum(counts) - counts
+    table = np.full((counts.size, width), e_pad, dtype=np.int32)
+    table[key, np.arange(key.size) - start[key]] = e[order]
+    return table.reshape(R, n_pad, width)
+
 
 def partition_elements(mesh: SEMMesh, rank_grid: Sequence[int]) -> np.ndarray:
     """Assign elements to ranks by blocks of the element grid.

@@ -18,8 +18,10 @@ Recording is always on: one span costs about a microsecond on the host when
 no profiler runs.
 
 Scopes on the device (``jax.named_scope``): ``enc``, ``nmp{i}`` with
-``edge_agg`` (Eq. 4a-b), ``halo`` (Eq. 4c-d) and ``node`` (Eq. 4e) inside,
-``vcycle/l{k}``, ``dec``, ``loss``, ``grad_sync``, ``adamw``. Host spans:
+``edge_agg`` (Eq. 4a-b; its table-driven sums, where the graph carries
+slot tables, under ``edge_agg/slot_sum``), ``halo`` (Eq. 4c-d) and ``node``
+(Eq. 4e) inside, ``vcycle/l{k}``, ``dec``, ``loss``, ``grad_sync``,
+``adamw``. Host spans:
 ``train/batch``, ``engine/queue_wait``, ``engine/gather``,
 ``engine/predict``, ``engine/scatter``. Programs: ``grad_step``,
 ``update``, ``rollout_predict``.

@@ -16,7 +16,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import GNNConfig, box_mesh, init_gnn, mesh_graph_edges
+from repro.core import (
+    GNNConfig, box_mesh, init_gnn, mesh_graph_edges, partition_mesh)
+from repro.core.consistent_mp import nmp_layer
+from repro.core.graph_state import NMPPlan, ShardedGraph
 from repro.core.mesh_gen import undirected_to_directed
 from repro.kernels.halo_pack.kernel import pack_pallas, unpack_add_pallas
 from repro.kernels.segment_agg.kernel import (
@@ -114,6 +117,37 @@ def test_fused_nmp_op_value_and_grad_compiles(one_chip, layout):
              _spec((n_round, H), one_chip), _spec((e_pad, H), one_chip),
              *(_spec((T, BE), one_chip, jnp.int32) for _ in range(3)),
              _spec((e_pad,), one_chip), _spec((e_pad,), one_chip))
+
+
+def test_xla_edge_agg_compiles_without_scatter_or_sort(one_chip):
+    """The xla backend's Eq. 4a-b, forward and backward, on the smoke mesh:
+    a bounded-degree graph carries slot tables, so the compiled layer sums
+    and transposes its gathers by gathers, with no scatter and no sort
+    under ``edge_agg``."""
+    import re
+    mesh = box_mesh(ELEMENTS, p=ORDER)
+    graph = ShardedGraph.build(partition_mesh(mesh, (1, 1, 1)), mesh.coords)
+    assert "in_slots" in graph
+    local = jax.tree.map(lambda a: _spec(a.shape[1:], one_chip, a.dtype), graph)
+    params = jax.eval_shape(lambda: init_gnn(
+        jax.random.PRNGKey(0), GNNConfig.large()))["mp"][0]
+    n_pad, e_pad = graph["node_mask"].shape[-1], graph["edge_mask"].shape[-1]
+
+    def loss(p, x, e, g):
+        with jax.named_scope("nmp0"):
+            x_new, e_new = nmp_layer(p, x, e, g, NMPPlan())
+        return (x_new ** 2).sum() + (e_new ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        jax.tree.map(lambda a: _spec(a.shape, one_chip, a.dtype), params),
+        _spec((n_pad, H), one_chip), _spec((e_pad, H), one_chip),
+        local).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("nmp0)/edge_agg/slot_sum" in o for o in op_names)
+    under = [line for line in text.splitlines()
+             if re.search(r"\b(scatter|sort)\(", line.split("metadata=")[0])
+             and "/edge_agg/" in line]
+    assert under == []
 
 
 @pytest.mark.parametrize("wire_rows", [128, 2048])

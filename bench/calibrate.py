@@ -15,7 +15,9 @@ its ``calibrate``:
   ``Precision.HIGH``, the step below the configuration's ``HIGHEST``, put
   in the program's place, on the first ``--control-seeds`` seeds;
 * ``half_batch`` (training): the reference with half of the batch left out,
-  its loss the mean over the first half of the nodes, on the same seeds.
+  its loss the mean over the first half of the nodes, on the same seeds;
+* ``no_exchange`` (training on more than one rank): the program with the
+  halo exchange between chips left out, on the same seeds.
 
 A state left unchanged reads 1 on ``grad_gap`` and ``change_gap`` by the
 comparison's own measure and needs no run. The output, with the largest

@@ -9,7 +9,8 @@ The fields are Taylor-Green snapshots at times drawn from the seed, made
 before the window; request ``i`` sends field ``i`` modulo the pool.
 
 After the window a sample of the requests, drawn from the seed, with the
-last one in it, is compared with the plain reference's forward pass.
+last one in it, is compared with the forward pass of the plain reference
+the configuration names (``bench/reference.py`` by default).
 ``calibrate`` gives the readings the limits are set from
 (``bench/calibrate.py``).
 """
@@ -30,13 +31,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from bench import compare, flops, harness, reference, trace
+from bench import compare, flops, harness, trace
 
 
-def fields(conf: dict, mix: dict, coords: np.ndarray, seed: int) -> list[np.ndarray]:
+def fields(conf: dict, mix: dict, coords: np.ndarray, seed: int, ref=None) -> list[np.ndarray]:
     """The pool of snapshots the client sends, at times drawn from the seed."""
     rng = np.random.default_rng(seed)
-    return [reference.taylor_green(coords, t, mix["nu"])
+    ref = ref or harness.reference_module(conf)
+    return [ref.taylor_green(coords, t, mix["nu"])
             for t in rng.uniform(0.0, mix["t_period"], mix["pool"])]
 
 
@@ -47,7 +49,7 @@ def build(conf: dict, mix: dict, devices, seed: int, span) -> SimpleNamespace:
     from repro.runtime.engine import EngineConfig, InferenceEngine
     from repro.train.loop import mesh_fingerprint_hash
 
-    mesh_cf = conf["mesh"]
+    mesh_cf = harness.mesh_of(conf, mix)
     gcfg = GNNConfig(name=conf["name"], **conf["model"])
     ranks = tuple(mesh_cf["ranks"])
     with span("mesh_gen"):
@@ -107,20 +109,30 @@ def program_memory(prog, mix: dict) -> int | None:
     return harness.program_bytes("rollout_eval", rollout_eval)
 
 
-def reference_predictions(conf: dict, seed: int, xs: dict, graph: reference.Graph,
-                          precision=reference.HIGHEST) -> dict:
-    """The reference's prediction for each field of ``xs`` (key -> field)."""
-    params = jax.jit(lambda k: reference.init_params(k, conf["model"]))(
+def reference_graph(conf: dict, mix: dict, ref=None):
+    """The reference's graph of the cell's mesh."""
+    mesh_cf = harness.mesh_of(conf, mix)
+    return (ref or harness.reference_module(conf)).Graph(mesh_cf["elements"], mesh_cf["order"])
+
+
+def reference_predictions(conf: dict, seed: int, xs: dict, graph, precision=None,
+                          ref=None) -> dict:
+    """The reference's prediction for each field of ``xs`` (key -> field),
+    at ``precision`` (the reference's own ``HIGHEST`` by default)."""
+    ref = ref or harness.reference_module(conf)
+    precision = ref.HIGHEST if precision is None else precision
+    params = jax.jit(lambda k: ref.init_params(k, conf["model"]))(
         jax.random.PRNGKey(seed))
-    forward = jax.jit(lambda p, x, g: reference.forward(p, x, g, precision))
+    forward = jax.jit(lambda p, x, g: ref.forward(p, x, g, precision))
     return {k: np.asarray(forward(params, x, graph.arrays)) for k, x in xs.items()}
 
 
 def run(cell) -> dict:
     conf, mix, rec = cell.config, cell.mix, cell.record
     span = rec.spans
+    ref = harness.reference_module(conf)
     prog = build(conf, mix, cell.devices, cell.seed, span)
-    engine, pool = prog.engine, fields(conf, mix, prog.coords, cell.seed)
+    engine, pool = prog.engine, fields(conf, mix, prog.coords, cell.seed, ref)
     rng = np.random.default_rng(cell.seed + 1)
 
     kept: dict[int, np.ndarray] = {}
@@ -160,15 +172,18 @@ def run(cell) -> dict:
         runtime_peak = trace.memory_peak(cell.devices)
         program = program_memory(prog, mix)
     rec.units = len(latencies)
-    rec.flops_per_unit = mix["rollout_steps"] * flops.forward_flops(
-        conf["model"], *flops.box_graph_size(conf["mesh"]["elements"], conf["mesh"]["order"]))
+    count = getattr(ref, "forward_flops", flops.forward_flops)
+    mesh_cf = harness.mesh_of(conf, mix)
+    rec.flops_per_unit = mix["rollout_steps"] * count(
+        conf["model"], *flops.box_graph_size(mesh_cf["elements"], mesh_cf["order"]))
     del prog, engine               # the program's device buffers are freed
     if latencies:
         kept[last[0]] = last[1]
 
-    graph = reference.Graph(conf["mesh"]["elements"], conf["mesh"]["order"])
+    graph = reference_graph(conf, mix, ref)
     refs = reference_predictions(conf, cell.seed,
-                                 {f: pool[f] for f in {i % len(pool) for i in kept}}, graph)
+                                 {f: pool[f] for f in {i % len(pool) for i in kept}}, graph,
+                                 ref=ref)
     gap = max((compare.prediction_gap(p, refs[i % len(pool)]) for i, p in kept.items()),
               default=math.inf)
     print(f"compared {len(kept)} of {len(latencies)} predictions", file=sys.stderr)
@@ -190,25 +205,27 @@ def calibrate(conf: dict, mix: dict, devices, seeds: list[int], n_control: int) 
     seeds."""
     from repro.core import init_gnn
 
+    ref = harness.reference_module(conf)
     prog = build(conf, mix, devices, seeds[0], lambda name: contextlib.nullcontext())
     engine = prog.engine
     init = jax.jit(lambda key: init_gnn(key, prog.gcfg))
     replicated = NamedSharding(engine.mesh_dev, P())
-    graph = reference.Graph(conf["mesh"]["elements"], conf["mesh"]["order"])
+    graph = reference_graph(conf, mix, ref)
     out = {"program": [], "control_high": []}
     with engine:
         engine.warmup()
         for i, seed in enumerate(seeds):
             # the weights the engine would load from this seed's checkpoint
             engine.params = jax.device_put(init(jax.random.PRNGKey(seed)), replicated)
-            pool = dict(enumerate(fields(conf, mix, prog.coords, seed)))
+            pool = dict(enumerate(fields(conf, mix, prog.coords, seed, ref)))
             got = {k: engine.submit(prog.mesh_hash, x).result().preds[0]
                    for k, x in pool.items()}
-            want = reference_predictions(conf, seed, pool, graph)
+            want = reference_predictions(conf, seed, pool, graph, ref=ref)
             out["program"].append({"seed": seed, "pred_gap": max(
                 compare.prediction_gap(got[k], want[k]) for k in pool)})
             if i < n_control:
-                ctrl = reference_predictions(conf, seed, pool, graph, jax.lax.Precision.HIGH)
+                ctrl = reference_predictions(conf, seed, pool, graph, jax.lax.Precision.HIGH,
+                                             ref)
                 out["control_high"].append({"seed": seed, "pred_gap": max(
                     compare.prediction_gap(ctrl[k], want[k]) for k in pool)})
             print(json.dumps({k: v[-1] for k, v in out.items() if v}), flush=True)

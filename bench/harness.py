@@ -2,11 +2,12 @@
 of compilations, the per-layer metric readers and the result line.
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix. The
-configuration is ``bench/configs/<config>.json``; the traffic mix is
-``bench/traffic/<traffic>.json``, whose ``kind`` names the driver
-``bench/drivers/<kind>.py`` that runs it; a per-layer metric ``<name>`` is
-read by ``bench/metrics/<name>.py``. Adding any of them adds files and
-entries, and edits none.
+configuration is ``bench/configs/<config>.json``, which may name its plain
+reference, a module under ``bench/`` (``bench/reference.py`` where it names
+none); the traffic mix is ``bench/traffic/<traffic>.json``, whose ``kind``
+names the driver ``bench/drivers/<kind>.py`` that runs it; a per-layer
+metric ``<name>`` is read by ``bench/metrics/<name>.py``. Adding any of them
+adds files and entries, and edits none.
 """
 from __future__ import annotations
 
@@ -60,6 +61,25 @@ def _load_file(path: Path) -> ModuleType:
 
 def driver(kind: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
     return _load_file(bench_dir / "drivers" / f"{kind}.py")
+
+
+def reference_module(conf: dict, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The plain reference a configuration names under ``reference`` (a file
+    under ``bench/``), or ``bench/reference.py`` where it names none. A
+    reference module has what ``bench/reference.py`` has: ``Graph``,
+    ``taylor_green``, ``init_params``, ``forward``, ``loss``, ``train`` and
+    ``HIGHEST``; a ``train_step_flops`` or ``forward_flops`` of its own
+    replaces ``bench/flops.py``'s count."""
+    if "reference" not in conf:
+        from bench import reference
+        return reference
+    return _load_file(bench_dir / conf["reference"])
+
+
+def mesh_of(conf: dict, mix: dict) -> dict:
+    """The cell's mesh and rank grid: the traffic mix's ``mesh`` where it
+    names one, else the configuration's."""
+    return mix.get("mesh", conf["mesh"])
 
 
 def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:

@@ -70,13 +70,14 @@ def limits(name: str) -> dict:
 
 def run_cell(bench: dict, name: str, seed: int, seconds: float, traced: bool,
              devices: list, config: dict | None = None, lims: dict | None = None,
-             t_start: float = T_START) -> dict:
-    """Run the cell and return its result line as a dict. ``config`` and
-    ``lims`` replace the cell's configuration file and limits, for tests."""
+             t_start: float = T_START, mix: dict | None = None) -> dict:
+    """Run the cell and return its result line as a dict. ``config``,
+    ``mix`` and ``lims`` replace the cell's configuration file, traffic file
+    and limits, for tests."""
     from bench import harness, peaks
 
     wl = harness.workload(bench, name)
-    mix = harness.traffic(wl["traffic"])
+    mix = mix or harness.traffic(wl["traffic"])
     config = config or harness.config(bench, wl["config"])
     lims = lims or limits(name)
     kind = devices[0].device_kind

@@ -16,6 +16,12 @@ then by the other programs' tables (``update``); how many names of the
 window collide, and the share of op time no table maps to a layer, are
 printed on standard error.
 
+Op time is summed over the chips and divided by their number, so a layer's
+device ms on several chips is one chip's, on average.
+
+The same compiled text gives each instruction's opcode, from which
+``exposed_collective_ms`` picks the collectives of the window.
+
 Every reader returns ``None`` where the run has no trace, is of another
 kind, or the program registered nothing (a program without ``repro.obs``).
 """
@@ -34,7 +40,9 @@ NMP_PARTS = ("edge_agg", "halo", "node")
 LEVEL = re.compile(r"l\d+")
 
 EDGE_AGG = re.compile(r"nmp\d+/edge_agg")
+HALO = re.compile(r"nmp\d+/halo")
 NODE = re.compile(r"nmp\d+/node")
+GRAD_SYNC = re.compile(r"grad_sync")
 ENCDEC_TRAIN = re.compile(r"enc|dec|loss")
 ENCDEC_INFER = re.compile(r"enc|dec")
 
@@ -42,9 +50,17 @@ _TRANSFORM = re.compile(r"[\w.\-]+\(")
 _NAME = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _OPERAND = re.compile(r"%([\w.\-]+)")
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s")
 TOP = 5
 
+#: collective opcodes; their async forms end in ``-start`` / ``-done``
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+               "all-to-all")
+
 _layer_ms: dict[int, tuple] = {}
+_texts: dict[int, tuple] = {}
 
 
 def layer_of(op_name: str) -> str | None:
@@ -93,6 +109,40 @@ def table(hlo_text: str) -> dict[str, str | None]:
     return out
 
 
+def is_collective(opcode: str) -> bool:
+    for suffix in ("-start", "-done"):
+        opcode = opcode.removesuffix(suffix)
+    return opcode in COLLECTIVES
+
+
+def collectives(hlo_text: str) -> dict[str, bool]:
+    """Instruction name -> whether it is a collective, for every instruction
+    of an HLO module's text: a collective opcode (or its ``-start`` /
+    ``-done``), or a fusion or async op whose called computation holds one."""
+    out: dict[str, bool] = {}
+    holds: dict[str, bool] = {}
+    calls: dict[str, list[str]] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        c = _COMPUTATION.match(line)
+        if c and line.rstrip().endswith("{"):
+            comp = c.group(1)
+            holds.setdefault(comp, False)
+            continue
+        m = _NAME.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        op = _OPCODE.search(line, m.end())
+        out[name] = bool(op) and is_collective(op.group(1))
+        calls[name] = _CALLS.findall(line)
+        if comp is not None:
+            holds[comp] = holds[comp] or out[name]
+    for name, called in calls.items():
+        out[name] = out[name] or any(holds.get(c, False) for c in called)
+    return out
+
+
 def attribute(ops: dict, tables: list[dict]) -> tuple[dict, dict, int]:
     """Seconds of op time per layer. A name goes by the first table that
     holds it. Returns the layers' seconds, the seconds of each name no table
@@ -120,18 +170,35 @@ def _obs():
     return obs
 
 
-def _attribute_run(rec) -> dict | None:
+def _programs(rec) -> tuple[list[str], list[str]] | None:
+    """The names of the programs that ran, the model's first, and their
+    compiled text; read once per trace."""
+    key = id(rec.trace)
+    if key not in _texts:
+        _texts[key] = (rec.trace, _read_programs(rec))
+    return _texts[key][1]
+
+
+def _read_programs(rec):
     obs = _obs()
     model = MODEL.get(rec.kind)
     if obs is None or model not in obs.programs():
         return None
     try:
         names = [model] + [n for n in obs.programs() if n != model]
-        tables = [table(obs.hlo(n)) for n in names]
+        return names, [obs.hlo(n) for n in names]
     except Exception as e:          # noqa: BLE001 - reported, not raised
         print(f"scopes: the compiled text of {model} is not available ({e!r})",
               file=sys.stderr)
         return None
+
+
+def _attribute_run(rec) -> dict | None:
+    progs = _programs(rec)
+    if progs is None:
+        return None
+    names, tables = progs[0], [table(t) for t in progs[1]]
+    model = names[0]
     ops = rec.trace["ops"]
     by, unmapped, collide = attribute(ops, tables)
     total = sum(ops.values()) or 1.0
@@ -140,7 +207,7 @@ def _attribute_run(rec) -> dict | None:
           f"{names[1:]} both; unmapped {100.0 * sum(unmapped.values()) / total:.3f}% of "
           f"{total:.6f} s of op time (largest: {worst}); per layer, s: "
           f"{ {k: round(v, 6) for k, v in sorted(by.items())} }", file=sys.stderr)
-    return {k: 1e3 * v / rec.units for k, v in by.items()}
+    return {k: 1e3 * v / rec.units / rec.trace["n_devices"] for k, v in by.items()}
 
 
 def layer_ms(rec) -> dict | None:
@@ -163,6 +230,31 @@ def device_ms(rec, kind: str, layers: re.Pattern) -> float | None:
     if by is None:
         return None
     return sum(v for k, v in by.items() if layers.fullmatch(k))
+
+
+def exposed_collective_ms(rec, kind: str) -> float | None:
+    """Device ms per step or request in which a collective runs on a chip
+    and no other op runs there, averaged over the chips; None where no
+    collective ran in the window."""
+    if rec.kind != kind or rec.trace is None or not rec.units:
+        return None
+    progs = _programs(rec)
+    if progs is None:
+        return None
+    tables = [collectives(t) for t in progs[1]]
+    picked = {}
+
+    def collective(name):
+        if name not in picked:
+            picked[name] = next((t[name] for t in tables if name in t), False)
+        return picked[name]
+    from bench import trace
+    s = trace.exposed_s(rec.trace, collective)
+    coll = {n: v for n, v in rec.trace["ops"].items() if collective(n)}
+    print(f"collectives: {len(coll)} op names, {sum(coll.values()):.6f} s of op time "
+          f"over the chips (largest: {sorted(coll.items(), key=lambda kv: -kv[1])[:TOP]}); "
+          f"exposed {s} s a chip", file=sys.stderr)
+    return None if s is None else 1e3 * s / rec.units
 
 
 def span_ms(rec, kind: str, name: str) -> float | None:

@@ -1,6 +1,5 @@
 """Whole runs of each cell at a tiny mesh on the CPU, and the refusal to run
 without a chip."""
-import json
 import os
 import subprocess
 import sys
@@ -8,9 +7,9 @@ import sys
 import pytest
 
 from bench import harness
-from bench.tests.conftest import run_tiny
+from bench.tests.conftest import limits, run_tiny, with_r4
 
-CELLS = [w["name"] for w in harness.load_benchmark()["workloads"]]
+CELLS = [w["name"] for w in with_r4(harness.load_benchmark())["workloads"]]
 
 
 def test_without_a_tpu_exits_nonzero_and_prints_no_result():
@@ -33,5 +32,4 @@ def test_cell_runs_correct_at_a_tiny_mesh(bench, cell):
     wanted = {m["name"] for m in harness.metrics_of(bench, cell, "end_to_end")}
     assert reported == wanted
     assert all(v["value"] > 0 for v in res["metrics"].values())
-    assert list(res["checks"]) == list(json.loads(
-        (harness.BENCH_DIR / "limits" / f"{cell}.json").read_text()))
+    assert list(res["checks"]) == list(limits(cell))

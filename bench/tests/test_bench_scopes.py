@@ -2,6 +2,7 @@
 program's host spans, as the new per-layer readers read them."""
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 from bench import harness, scopes
@@ -70,9 +71,9 @@ class FakeObs:
         return [s for s in self._spans if t0 <= s[1] and s[2] <= t1]
 
 
-def record(kind="train", trace=True, units=2):
+def record(kind="train", trace=True, units=2, n_devices=1):
     return SimpleNamespace(kind=kind, units=units, window=(10.0, 20.0),
-                           trace={"ops": dict(OPS)} if trace else None)
+                           trace={"ops": dict(OPS), "n_devices": n_devices} if trace else None)
 
 
 def test_layer_of_takes_off_transforms_and_reads_the_first_layer():
@@ -147,3 +148,82 @@ def test_new_readers_give_none_where_the_program_has_no_tracing(monkeypatch):
     for kind, names in NEW_READERS.items():
         for name in names:
             assert harness.metric_reader(name).read(record(kind)) is None, name
+
+
+#: a grad step on several chips: collectives as plain ops, in their async
+#: forms, and inside a fused computation
+SHARDED = """\
+HloModule jit_call, entry_computation_layout={()->f32[]}
+
+%add.1 (a.1: f32[], b.1: f32[]) -> f32[] {
+  %a.1 = f32[] parameter(0)
+  %b.1 = f32[] parameter(1)
+  ROOT %add.2 = f32[] add(%a.1, %b.1)
+}
+
+%fused_computation.7 (param_0.3: f32[8]) -> f32[32] {
+  %param_0.3 = f32[8]{0} parameter(0)
+  ROOT %all-gather.4 = f32[32]{0} all-gather(%param_0.3), dimensions={0}
+}
+
+ENTRY %main.3 (x.1: f32[8]) -> f32[8] {
+  %x.1 = f32[8]{0} parameter(0)
+  %fusion.1 = f32[8]{0:T(256)} fusion(%x.1), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(call)/jvp(nmp0)/edge_agg/mul"}
+  %collective-permute-start.2 = (f32[8]{0}, f32[8]{0}, u32[], u32[]) collective-permute-start(%fusion.1), source_target_pairs={{0,1},{1,0}}, metadata={op_name="jit(call)/jvp(nmp0)/halo/ppermute"}
+  %collective-permute-done.2 = f32[8]{0} collective-permute-done(%collective-permute-start.2), metadata={op_name="jit(call)/jvp(nmp0)/halo/ppermute"}
+  %all-reduce.5 = f32[8]{0} all-reduce(%collective-permute-done.2), replica_groups={{0,1}}, to_apply=%add.1, metadata={op_name="jit(call)/grad_sync/psum"}
+  %all-reduce-start.6 = f32[8]{0} all-reduce-start(%all-reduce.5), to_apply=%add.1, metadata={op_name="jit(call)/grad_sync/psum"}
+  %all-reduce-done.6 = f32[8]{0} all-reduce-done(%all-reduce-start.6), metadata={op_name="jit(call)/grad_sync/psum"}
+  %fusion.8 = f32[32]{0} fusion(%all-reduce-done.6), kind=kCustom, calls=%fused_computation.7, metadata={op_name="jit(call)/jvp(nmp0)/halo/all_gather"}
+  ROOT %add.9 = f32[8]{0} add(%all-reduce-done.6, %x.1), metadata={op_name="jit(call)/jvp(nmp0)/node/add"}
+}
+"""
+
+
+def test_collectives_by_opcode_async_forms_and_fused():
+    t = scopes.collectives(SHARDED)
+    assert {n for n, c in t.items() if c} == {
+        "collective-permute-start.2", "collective-permute-done.2", "all-reduce.5",
+        "all-reduce-start.6", "all-reduce-done.6", "fusion.8", "all-gather.4"}
+    assert not t["fusion.1"] and not t["add.9"] and not t["add.2"]
+    assert scopes.is_collective("all-to-all") and not scopes.is_collective("all-reduce-scatter")
+
+
+def sharded_record(units=2):
+    """Two chips over a 10-s window: on chip 0, the halo's permute half
+    hidden under the edge layer's fusion and the gradients' all-reduce alone;
+    on chip 1, the all-reduce alone."""
+    iv0 = np.array([[10.0, 12.0], [11.0, 13.0], [14.0, 15.0], [16.0, 16.5]])
+    names0 = ["fusion.1", "collective-permute-done.2", "all-reduce-done.6", "add.9"]
+    iv1 = np.array([[10.0, 12.0], [14.0, 16.0]])
+    names1 = ["fusion.1", "all-reduce-done.6"]
+    ops = {"fusion.1": 4.0, "collective-permute-done.2": 2.0, "all-reduce-done.6": 3.0,
+           "add.9": 0.5}
+    return SimpleNamespace(kind="train", units=units, window=(10.0, 20.0), trace={
+        "ops": ops, "n_devices": 2, "window": (10.0, 20.0),
+        "op_intervals": [(iv0, names0), (iv1, names1)]})
+
+
+def test_readers_of_the_sharded_cell(monkeypatch, capsys):
+    monkeypatch.setattr(scopes, "_obs", lambda: FakeObs({"grad_step": SHARDED}))
+    rec = sharded_record()
+    # op time summed over both chips, over 2 chips and 2 steps, in ms
+    assert harness.metric_reader("halo_ms.train").read(rec) == pytest.approx(500.0)
+    assert harness.metric_reader("grad_sync_ms.train").read(rec) == pytest.approx(750.0)
+    assert harness.metric_reader("edge_agg_ms.train").read(rec) == pytest.approx(1000.0)
+    # chip 0: 1 s of the permute beyond the fusion, 1 s of the all-reduce;
+    # chip 1: 2 s of the all-reduce; mean 2 s, over 2 steps
+    assert harness.metric_reader("exposed_collective_ms.train").read(rec) == pytest.approx(1000.0)
+    assert "collectives: 2 op names" in capsys.readouterr().err
+    for name in ("halo_ms.train", "grad_sync_ms.train", "exposed_collective_ms.train"):
+        assert harness.metric_reader(name).read(SimpleNamespace(
+            kind="infer", units=2, window=(10.0, 20.0), trace=rec.trace)) is None
+        assert harness.metric_reader(name).read(SimpleNamespace(
+            kind="train", units=2, window=(10.0, 20.0), trace=None)) is None
+
+
+def test_exposed_collective_ms_is_none_where_no_collective_ran(monkeypatch):
+    monkeypatch.setattr(scopes, "_obs", lambda: FakeObs({"grad_step": GRAD_STEP}))
+    rec = sharded_record()
+    rec.trace["op_intervals"] = [(np.array([[10.0, 12.0]]), ["fusion.1"])]
+    assert scopes.exposed_collective_ms(rec, "train") is None

@@ -46,6 +46,44 @@ def test_reduce_synthetic_trace():
     assert dur == pytest.approx(7e-6) and busy == pytest.approx(4e-6)
 
 
+def test_reduce_keeps_each_devices_op_intervals():
+    r = trace.reduce(synthetic())
+    assert r["n_devices"] == 1 and r["window"] == pytest.approx((0.0, 10e-6))
+    (iv, names), = r["op_intervals"]
+    np.testing.assert_allclose(iv, [[1e-6, 3e-6], [2e-6, 4e-6], [6e-6, 7e-6]])
+    assert names == ["fusion.1", "scatter.2", "fusion.1"]
+
+
+def collectives_profile():
+    """Two devices over a window of 20 us. Device 0: a collective hidden
+    under compute ([2, 4] inside [1, 5]), one half exposed ([8, 12] against
+    compute in [6, 10]), and one alone ([14, 15]). Device 1: one collective
+    alone ([3, 6]) and compute in [10, 11]."""
+    dev0 = _plane(1, "/device:TPU:0", "XLA Ops", [
+        ("%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop", 1, 5),
+        ("%all-reduce-start.2 = f32[8]{0} all-reduce-start(f32[8]{0} %a)", 2, 4),
+        ("%fusion.3 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop", 6, 10),
+        ("%collective-permute-done.4 = f32[8]{0} collective-permute-done(f32[8]{0} %a)", 8, 12),
+        ("%all-reduce-done.2 = f32[8]{0} all-reduce-done(f32[8]{0} %a)", 14, 15)])
+    dev1 = _plane(3, "/device:TPU:1", "XLA Ops", [
+        ("%all-reduce-done.2 = f32[8]{0} all-reduce-done(f32[8]{0} %a)", 3, 6),
+        ("%fusion.3 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop", 10, 11)])
+    host = _plane(2, "/host:CPU", "python", [("bench:window", 0, 20)])
+    return ProfileData.from_text_proto(dev0 + dev1 + host)
+
+
+def test_exposed_time_of_collectives_hidden_half_exposed_and_alone():
+    r = trace.reduce(collectives_profile())
+    picked = lambda name: not name.startswith("fusion")  # noqa: E731
+    # device 0: 0 (hidden) + 2 (half of [8, 12]) + 1 (alone); device 1: 3
+    assert trace.exposed_s(r, picked) == pytest.approx((3e-6 + 3e-6) / 2)
+    assert trace.exposed_s(r, lambda name: False) is None
+    # the outputs that were there before stay as they were: busy time is the
+    # mean of the devices' unions, op time the sum over devices
+    assert r["busy_s"] == pytest.approx((4e-6 + 6e-6 + 1e-6 + 3e-6 + 1e-6) / 2)
+    assert r["ops"]["fusion.3"] == pytest.approx(5e-6)
+
+
 def test_union_merges_overlaps():
     iv = np.array([[5.0, 6.0], [0.0, 2.0], [1.0, 3.0], [3.0, 4.0], [7.0, 7.5]])
     np.testing.assert_array_equal(trace._union(iv), [[0.0, 4.0], [5.0, 6.0], [7.0, 7.5]])

@@ -12,7 +12,9 @@ benchmark's host spans into trace annotations; ``reduce`` reads the
 * the idle gaps between busy intervals, each attributed to the innermost
   benchmark span that covers most of it;
 * for each span name, the duration and the device busy time inside each
-  occurrence.
+  occurrence;
+* each device's op intervals with their names, from which ``exposed_s``
+  takes the time in which a given kind of op runs alone.
 """
 from __future__ import annotations
 
@@ -150,4 +152,22 @@ def reduce(profile) -> dict:
 
     return {"window_s": w1 - w0, "busy_s": busy, "ops": ops,
             "spans": by_span,
-            "breakdown": {"device_ops": top(ops), "idle_gaps": top(gaps)}}
+            "breakdown": {"device_ops": top(ops), "idle_gaps": top(gaps)},
+            "window": (w0, w1), "n_devices": len(devices),
+            "op_intervals": [(iv, [n for _, _, n in evs]) for iv, evs in devices]}
+
+
+def exposed_s(reduced: dict, picked) -> float | None:
+    """Seconds of the window in which an op that ``picked(name)`` selects
+    runs on a device and no other op runs there, averaged over the devices;
+    None where no op of the window is selected."""
+    w0, w1 = reduced["window"]
+    total, found = 0.0, False
+    for iv, names in reduced["op_intervals"]:
+        sel = np.array([picked(n) for n in names], bool)
+        inside = (iv[:, 1] > w0) & (iv[:, 0] < w1)
+        if not (sel & inside).any():
+            continue
+        found = True
+        total += _overlap(_union(iv), w0, w1) - _overlap(_union(iv[~sel]), w0, w1)
+    return total / len(reduced["op_intervals"]) if found else None
